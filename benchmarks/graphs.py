@@ -303,6 +303,37 @@ def stacked_args(rng, num_layers: int = 8):
     )
 
 
+def swiglu_fn(x, gains, w_gate, w_up, w_down):
+    """Pre-norm SwiGLU MLP blocks (the qwen/llama FFN) in plain jnp: RMSNorm,
+    ``silu(x Wg) * (x Wu)``, down projection, residual add."""
+    for g, wg, wu, wd in zip(gains, w_gate, w_up, w_down, strict=False):
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        normed = x * jax.lax.rsqrt(ms + 1e-6) * g
+        h = jax.nn.silu(jnp.matmul(normed, wg)) * jnp.matmul(normed, wu)
+        x = x + jnp.matmul(h, wd)
+    return x
+
+
+def swiglu_args(rng, tokens: int, d_model: int, d_ff: int, num_layers: int = 2):
+    s_in, s_ff = d_model ** -0.5, d_ff ** -0.5
+    return (
+        rng.randn(tokens, d_model).astype("f4"),
+        [(1.0 + 0.1 * rng.randn(d_model)).astype("f4") for _ in range(num_layers)],
+        [(s_in * rng.randn(d_model, d_ff)).astype("f4") for _ in range(num_layers)],
+        [(s_in * rng.randn(d_model, d_ff)).astype("f4") for _ in range(num_layers)],
+        [(s_ff * rng.randn(d_ff, d_model)).astype("f4") for _ in range(num_layers)],
+    )
+
+
+def softmax_transpose_fn(x, g):
+    """``stitch_pipeline_graph`` in plain jnp: a gained row-softmax feeding
+    a full 2-D transpose and a tail op — one multi-phase stitched kernel."""
+    scaled = x * g
+    e = jnp.exp(scaled - jnp.max(scaled, axis=1, keepdims=True))
+    p = e / jnp.sum(e, axis=1, keepdims=True)
+    return jnp.tanh(p.T) * 0.5
+
+
 def reduce_towers_fn(xs, ss):
     """Independent square/scale/reduce towers in plain jnp — mirrors
     ``reduce_towers_graph`` (the horizontal-merge adversary)."""

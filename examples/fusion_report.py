@@ -6,14 +6,10 @@ VMEM scratch, sharing) — the compiler's explain-mode.
 """
 import sys
 
-import jax
-
-jax.config.update("jax_platform_name", "cpu")
-
 sys.path.insert(0, ".")  # for benchmarks.*
 
-from benchmarks.graphs import ALL_GRAPHS  # noqa: E402
-from repro.core import StitchOptions, compile_module  # noqa: E402
+from benchmarks.graphs import ALL_GRAPHS
+from repro.core import StitchOptions, compile_module
 
 
 def main():

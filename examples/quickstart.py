@@ -13,9 +13,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_platform_name", "cpu")
-
-from repro import StitchOptions, stitch  # noqa: E402
+from repro import StitchOptions, stitch
 
 
 @stitch(options=StitchOptions(max_blocks=32))

@@ -12,15 +12,12 @@ slot engine would cap concurrency at its pool size.
 """
 import time
 
+import jax
 import numpy as np
 
-import jax
-
-jax.config.update("jax_platform_name", "cpu")
-
-from repro.configs import get_config, reduced_config  # noqa: E402
-from repro.models import init_params  # noqa: E402
-from repro.serve import PagedServeEngine, Request  # noqa: E402
+from repro.configs import get_config, reduced_config
+from repro.models import init_params
+from repro.serve import PagedServeEngine, Request
 
 
 def main():
@@ -70,7 +67,7 @@ def main():
     kv = st["kv_blocks"]
     print(f"\nserved {len(done)}/{len(requests)} requests, "
           f"{total_toks} tokens in {dt:.2f}s "
-          f"({total_toks / dt:.1f} tok/s on 1 CPU core, "
+          f"({total_toks / dt:.1f} tok/s on {jax.devices()[0].device_kind}, "
           f"width=16, {kv['num_blocks']}x{kv['block_size']}-token blocks)")
     print(f"prefill launches: {st['prefill_launches']} for "
           f"{st['prefill_tokens']} prompt tokens; "

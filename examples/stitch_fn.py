@@ -23,9 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_platform_name", "cpu")
-
-from repro import (  # noqa: E402
+from repro import (
     StitchOptions,
     UnsupportedPrimitiveError,
     compile_module,
@@ -33,7 +31,7 @@ from repro import (  # noqa: E402
 )
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from graphs import JNP_FAMILIES, nmt_args  # noqa: E402
+from graphs import JNP_FAMILIES, nmt_args
 
 OPTS = StitchOptions(max_blocks=64)
 

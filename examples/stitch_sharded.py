@@ -15,6 +15,10 @@ one ``lax.psum`` merging the partial block outputs) compiled through
     per-device kernel count as the single-device plan.
 
     PYTHONPATH=src python examples/stitch_sharded.py
+
+It stays on the CPU on purpose: the mesh needs 8 devices, which the host
+platform provides on any machine, and a one-chip TPU host cannot.  The same
+path on four real chips is ``python chip_smoke.py --chips 4``.
 """
 import os
 
@@ -29,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+# the 8 host devices above, even where a TPU is attached (see docstring)
 jax.config.update("jax_platform_name", "cpu")
 
 from repro import StitchOptions, stitch  # noqa: E402

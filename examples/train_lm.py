@@ -10,13 +10,11 @@ import tempfile
 
 import jax
 
-jax.config.update("jax_platform_name", "cpu")
-
-from repro.checkpoint import CheckpointManager  # noqa: E402
-from repro.configs import get_config, reduced_config  # noqa: E402
-from repro.data import SyntheticLM  # noqa: E402
-from repro.models import count_params, init_params  # noqa: E402
-from repro.train import (  # noqa: E402
+from repro.checkpoint import CheckpointManager
+from repro.configs import get_config, reduced_config
+from repro.data import SyntheticLM
+from repro.models import count_params, init_params
+from repro.train import (
     AdamWConfig,
     Trainer,
     TrainerConfig,

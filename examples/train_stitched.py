@@ -13,11 +13,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_platform_name", "cpu")
-
-from repro import StitchOptions  # noqa: E402
-from repro.train import AdamWConfig, adamw_init, make_stitched_train_step  # noqa: E402
-from repro.train.optimizer import adamw_update  # noqa: E402
+from repro import StitchOptions
+from repro.train import AdamWConfig, adamw_init, make_stitched_train_step
+from repro.train.optimizer import adamw_update
 
 BATCH, D_IN, D_H, D_OUT = 64, 16, 32, 8
 

@@ -18,7 +18,10 @@ Emits ONE ``pl.pallas_call`` per fused computation:
 
 The same ``apply_op`` interpreter evaluates ops here (on VMEM tiles) and in
 the reference executor (on full arrays), so kernels match the oracle by
-construction up to float reassociation.
+construction up to float reassociation.  Two ops leave it for forms Mosaic
+lowers: block windows are static slices or ``pl.ds`` reads of an input
+ref (never a value ``dynamic_slice``), and batched dots run as 2-D or
+single-batch-dim matmuls.
 """
 from __future__ import annotations
 
@@ -31,16 +34,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU scratch memory spaces; interpret mode accepts them on CPU too
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .fusion import FusedComputation
 from .ir import Instruction, apply_op
-from .memory import ALLOC, INLINE, SHARE, MemoryPlan, StitchedMemoryPlan
+from .memory import ALLOC, SCOPED_VMEM_BYTES, SHARE, MemoryPlan, StitchedMemoryPlan
 from .schedule import (
     REPLICATED,
     Sched,
@@ -51,11 +49,88 @@ from .schedule import (
     propagate,
 )
 
+#: VMEM Mosaic keeps for itself on top of the planned buffers.
+VMEM_HEADROOM = 4 * 1024 * 1024
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """``None`` means: run the Pallas interpreter exactly when there is no
+    TPU.  On a TPU kernels compile for the chip unless told otherwise."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def _compiler_params(vmem_need: int):
+    """Raise the kernel's scoped-VMEM limit when its plan needs more than
+    the chip gives by default."""
+    if vmem_need + VMEM_HEADROOM <= SCOPED_VMEM_BYTES:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_need + VMEM_HEADROOM)
+
+
+# Pallas TPU blocks have rank >= 1: rank-0 kernel operands, results and
+# scratch travel as (1, 1) blocks and are reshaped back inside the kernel.
+
+
+def _lift(shape) -> tuple:
+    return tuple(shape) if len(shape) else (1, 1)
+
+
+def _load(ref, shape):
+    v = ref[...]
+    return v.reshape(()) if not len(shape) else v
+
+
+def _store(ref, v) -> None:
+    ref[...] = v.reshape(ref.shape) if v.ndim == 0 else v
+
+
+class _Input:
+    """A kernel input: its ref, loaded whole on first use.  A block that
+    needs only a window of it reads the window from the ref instead."""
+
+    def __init__(self, ref, shape):
+        self.ref = ref
+        self.shape = tuple(shape)
+        self._val = None
+
+    @property
+    def value(self):
+        if self._val is None:
+            self._val = _load(self.ref, self.shape)
+        return self._val
+
+
+def _value(v):
+    return v.value if isinstance(v, _Input) else v
+
 
 def _starts(shape, sched: Sched, b):
+    """Start of block ``b``'s window; a dim the block covers whole starts
+    at a static 0 whatever ``b`` is."""
     idx = block_index(shape, sched, b)
     cs = chunk_shape(shape, sched)
-    return tuple(i * c for i, c in zip(idx, cs, strict=False))
+    return tuple(
+        0 if c == n else i * c for i, c, n in zip(idx, cs, shape, strict=False)
+    )
+
+
+def _window(v, starts, sizes):
+    """The ``sizes`` window of ``v`` at ``starts``.  Mosaic has no value
+    ``dynamic_slice``: static starts slice the value, traced starts read
+    the window from the input's ref with ``pl.ds``."""
+    if all(isinstance(st, int) for st in starts):
+        val = _value(v)
+        if tuple(sizes) == tuple(val.shape):
+            return val
+        return val[tuple(slice(st, st + n) for st, n in zip(starts, sizes, strict=False))]
+    if not isinstance(v, _Input):
+        raise ValueError(
+            "a block-dependent window of an in-kernel value has no Mosaic "
+            "lowering; the schedule must deliver it as a block"
+        )
+    return v.ref[tuple(pl.ds(st, n) for st, n in zip(starts, sizes, strict=False))]
 
 
 def _adapt(val, opnd: Instruction, stored: Sched, needed: Sched, b):
@@ -63,7 +138,7 @@ def _adapt(val, opnd: Instruction, stored: Sched, needed: Sched, b):
     if stored == needed:
         return val
     if stored.kind == "replicated" and needed.kind == "chunked":
-        return jax.lax.dynamic_slice(
+        return _window(
             val, _starts(opnd.shape, needed, b), chunk_shape(opnd.shape, needed)
         )
     if needed.kind == "replicated" and stored.kind == "replicated":
@@ -73,14 +148,51 @@ def _adapt(val, opnd: Instruction, stored: Sched, needed: Sched, b):
     )
 
 
+def _dot(instr: Instruction, lhs, rhs):
+    """A batched ``dot`` as Mosaic's matmul takes it: 2-D when the block
+    holds one batch, else with the batch dims folded into one.  f32 dots
+    contract at full f32 precision, as the oracle does (Mosaic's default
+    may round operands to bf16)."""
+    batch = tuple(lhs.shape[:-2])
+    nb = int(np.prod(batch, dtype=np.int64))
+    f32 = np.dtype(instr.dtype) == np.float32
+    kw = dict(
+        preferred_element_type=jnp.float32 if f32 else None,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+    )
+    if nb == 1:
+        out = jax.lax.dot_general(
+            lhs.reshape(lhs.shape[-2:]), rhs.reshape(rhs.shape[-2:]),
+            (((1,), (0,)), ((), ())), **kw,
+        )
+    else:
+        out = jax.lax.dot_general(
+            lhs.reshape((nb,) + lhs.shape[-2:]), rhs.reshape((nb,) + rhs.shape[-2:]),
+            (((2,), (1,)), ((0,), (0,))), **kw,
+        )
+    return out.reshape(batch + out.shape[-2:]).astype(instr.dtype)
+
+
+def _gather(instr: Instruction, table, idx):
+    """Row gather as a one-hot matmul (Mosaic has no vector gather).  The
+    one-hot rows pick table rows exactly at HIGHEST precision; a table that
+    fits a kernel is small, so the extra MXU work is small too."""
+    v = table.shape[0]
+    flat = idx.reshape((-1, 1)).astype(jnp.int32)
+    onehot = (flat == jax.lax.broadcasted_iota(jnp.int32, (flat.shape[0], v), 1))
+    rows = jax.lax.dot_general(
+        onehot.astype(table.dtype), table.reshape((v, -1)),
+        (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    return rows.reshape(tuple(idx.shape) + tuple(table.shape[1:])).astype(instr.dtype)
+
+
 def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
     """Evaluate one instruction on block tiles (thread-composition body)."""
     op = instr.opcode
     a = instr.attrs
     out_chunk = chunk_shape(instr.shape, sched)
-
-    if op in ("reshape", "bitcast"):
-        return jnp.reshape(ovals[0], out_chunk)
 
     if op == "broadcast":
         dims = tuple(a["dims"])
@@ -98,8 +210,12 @@ def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
                 out_chunk[dims[j]] if opnd.shape[j] != 1 else 1
                 for j in range(len(dims))
             )
-            v = jax.lax.dynamic_slice(v, starts, sizes)
-        return jax.lax.broadcast_in_dim(v, out_chunk, dims)
+            v = _window(v, starts, sizes)
+        return jax.lax.broadcast_in_dim(_value(v), out_chunk, dims)
+
+    ovals = [_value(v) for v in ovals]
+    if op in ("reshape", "bitcast"):
+        return jnp.reshape(ovals[0], out_chunk)
 
     if op == "iota":
         d = a["dim"]
@@ -108,6 +224,12 @@ def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
             start = _starts(instr.shape, sched, b)[d]
             base = base + jnp.asarray(start, dtype=instr.dtype)
         return base
+
+    if op == "dot":
+        return _dot(instr, *ovals)
+
+    if op == "gather":
+        return _gather(instr, *ovals)
 
     return apply_op(instr, *ovals)
 
@@ -160,7 +282,7 @@ def emit_fusion(
     fusion: FusedComputation,
     solution: ScheduleSolution,
     plan: MemoryPlan,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> StitchedKernel:
     members = fusion.members
     roots = fusion.roots
@@ -178,20 +300,24 @@ def emit_fusion(
                 "a kernel; it must stay a standalone schedule break"
             )
 
-    def in_spec(instr: Instruction) -> pl.BlockSpec:
-        sched = assign.get(instr.id, REPLICATED)
-        cs = chunk_shape(instr.shape, sched)
+    def spec(shape, sched: Sched) -> pl.BlockSpec:
+        if not shape:
+            return _full_spec(shape)
         return pl.BlockSpec(
-            cs, functools.partial(block_index, tuple(instr.shape), sched)
+            chunk_shape(shape, sched), functools.partial(block_index, shape, sched)
         )
 
-    in_specs = [in_spec(i) for i in inputs]
-    out_specs = [in_spec(r) for r in roots]
-    out_shape = [jax.ShapeDtypeStruct(tuple(r.shape), r.dtype) for r in roots]
-    scratch_shapes = []
-    if _VMEM is not None:
-        for sshape, sdtype in plan.slots:
-            scratch_shapes.append(_VMEM(tuple(sshape), np.dtype(sdtype)))
+    in_specs = [spec(tuple(i.shape), assign.get(i.id, REPLICATED)) for i in inputs]
+    # an exit reshape's block is its operand's; _boundary reshapes it after
+    out_blocks = [solution.block(r) for r in roots]
+    out_specs = [spec(*blk) for blk in out_blocks]
+    out_shape = [
+        jax.ShapeDtypeStruct(_lift(shape), r.dtype)
+        for (shape, _), r in zip(out_blocks, roots, strict=False)
+    ]
+    scratch_shapes = [
+        pltpu.VMEM(_lift(sshape), np.dtype(sdtype)) for sshape, sdtype in plan.slots
+    ]
 
     n_in, n_out = len(inputs), len(roots)
     root_pos = {r.id: j for j, r in enumerate(roots)}
@@ -200,12 +326,15 @@ def emit_fusion(
         in_refs = refs[:n_in]
         out_refs = refs[n_in: n_in + n_out]
         scratch = refs[n_in + n_out:]
-        b = pl.program_id(0)
+        # a one-block grid has static windows: no program_id at all
+        b = pl.program_id(0) if blocks > 1 else 0
 
         stored: Dict[int, Sched] = {}
         vals: Dict[int, object] = {}
         for i, instr in enumerate(inputs):
-            vals[instr.id] = in_refs[i][...]
+            vals[instr.id] = _Input(
+                in_refs[i], chunk_shape(instr.shape, assign.get(instr.id, REPLICATED))
+            )
             stored[instr.id] = assign.get(instr.id, REPLICATED)
 
         for m in members:
@@ -219,17 +348,20 @@ def emit_fusion(
                 _adapt(vals[o.id], o, stored[o.id], ns, b)
                 for o, ns in zip(m.operands, needed, strict=False)
             ]
-            v = _emit_instr(m, sched, ovals, b)
+            if m.id in solution.exits:
+                v = _value(ovals[0])
+            else:
+                v = _emit_instr(m, sched, ovals, b)
             entry = plan.entries.get(m.id)
-            if entry is not None and entry.action in (ALLOC, SHARE) and scratch:
+            if entry is not None and entry.action in (ALLOC, SHARE):
                 # block composition: stitch through the VMEM scratch slot
                 ref = scratch[entry.slot]
-                ref[...] = v
-                v = ref[...]
+                _store(ref, v)
+                v = _load(ref, v.shape)
             vals[m.id] = v
             stored[m.id] = sched
             if m.id in root_pos:
-                out_refs[root_pos[m.id]][...] = v
+                _store(out_refs[root_pos[m.id]], v)
 
     call = pl.pallas_call(
         kernel,
@@ -238,14 +370,10 @@ def emit_fusion(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=_compiler_params(plan.vmem_need),
     )
-
-    def fn(*args):
-        outs = call(*args)
-        return outs if isinstance(outs, (list, tuple)) else (outs,)
-
-    return StitchedKernel(fusion, solution, plan, fn, inputs, roots)
+    return StitchedKernel(fusion, solution, plan, _boundary(call, inputs, roots), inputs, roots)
 
 
 # --------------------------------------------------------------------------
@@ -253,16 +381,35 @@ def emit_fusion(
 # --------------------------------------------------------------------------
 
 
-def _full_spec(instr: Instruction) -> pl.BlockSpec:
+def _full_spec(shape) -> pl.BlockSpec:
     """Whole-tensor BlockSpec: the block IS the array (grid is trivial)."""
-    shape = tuple(instr.shape)
+    shape = _lift(shape)
     return pl.BlockSpec(shape, lambda b, _n=len(shape): (0,) * _n)
+
+
+def _boundary(call, inputs: List[Instruction], roots: List[Instruction]) -> Callable:
+    """Wrap a pallas_call so rank-0 operands cross it as (1, 1) blocks and
+    exit reshapes apply to its outputs."""
+
+    def fn(*args):
+        args = [
+            jnp.reshape(a, (1, 1)) if not i.shape else a
+            for a, i in zip(args, inputs, strict=False)
+        ]
+        outs = call(*args)
+        outs = outs if isinstance(outs, (list, tuple)) else (outs,)
+        return tuple(
+            o if o.shape == tuple(r.shape) else jnp.reshape(o, r.shape)
+            for o, r in zip(outs, roots, strict=False)
+        )
+
+    return fn
 
 
 def _store_chunk(ref, instr: Instruction, sched: Sched, v, b: int):
     """Write one block's value into a full-shape ref at static offsets."""
     if sched.kind == "replicated" or not instr.shape:
-        ref[...] = v
+        _store(ref, v)
         return
     starts = _starts(instr.shape, sched, b)
     cs = chunk_shape(instr.shape, sched)
@@ -273,7 +420,7 @@ def emit_stitched_fusion(
     fusion: FusedComputation,
     stitched: StitchedSolution,
     plan: StitchedMemoryPlan,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> StitchedKernel:
     """Emit ONE Pallas kernel running every phase of a stitched group.
 
@@ -285,8 +432,6 @@ def emit_stitched_fusion(
     per-block) by its consumer phases — shared-memory stitching across
     schedule breaks, per the FusionStitching follow-up work.
     """
-    if _VMEM is None:  # pragma: no cover - jax always ships pallas.tpu here
-        raise RuntimeError("stitched emission needs pallas TPU scratch spaces")
     for m in fusion.members:
         if m.is_collective:
             raise ValueError(
@@ -296,9 +441,9 @@ def emit_stitched_fusion(
     inputs = fusion.inputs
     roots = fusion.roots
 
-    in_specs = [_full_spec(i) for i in inputs]
-    out_specs = [_full_spec(r) for r in roots]
-    out_shape = [jax.ShapeDtypeStruct(tuple(r.shape), r.dtype) for r in roots]
+    in_specs = [_full_spec(i.shape) for i in inputs]
+    out_specs = [_full_spec(r.shape) for r in roots]
+    out_shape = [jax.ShapeDtypeStruct(_lift(r.shape), r.dtype) for r in roots]
 
     # scratch layout: interface staging buffers first, then each phase's
     # chunk-granular slots at a per-phase offset
@@ -306,12 +451,12 @@ def emit_stitched_fusion(
     iface_slot: Dict[int, int] = {}
     for iid, buf in plan.interfaces.items():
         iface_slot[iid] = len(scratch_shapes)
-        scratch_shapes.append(_VMEM(tuple(buf.shape), np.dtype(buf.dtype)))
+        scratch_shapes.append(pltpu.VMEM(_lift(buf.shape), np.dtype(buf.dtype)))
     phase_offsets: List[int] = []
     for pplan in plan.phase_plans:
         phase_offsets.append(len(scratch_shapes))
         for sshape, sdtype in pplan.slots:
-            scratch_shapes.append(_VMEM(tuple(sshape), np.dtype(sdtype)))
+            scratch_shapes.append(pltpu.VMEM(_lift(sshape), np.dtype(sdtype)))
 
     n_in, n_out = len(inputs), len(roots)
     root_pos = {r.id: j for j, r in enumerate(roots)}
@@ -323,7 +468,7 @@ def emit_stitched_fusion(
 
         global_vals: Dict[int, object] = {}
         for i, instr in enumerate(inputs):
-            global_vals[instr.id] = in_refs[i][...]
+            global_vals[instr.id] = _Input(in_refs[i], instr.shape)
 
         for pk, phase in enumerate(stitched.phases):
             assign = phase.solution.assignment
@@ -339,7 +484,7 @@ def emit_stitched_fusion(
                         and o.id not in global_vals
                         and plan.interfaces[o.id].produced_phase < pk
                     ):
-                        global_vals[o.id] = scratch[iface_slot[o.id]][...]
+                        global_vals[o.id] = _Input(scratch[iface_slot[o.id]], o.shape)
             for b in range(phase.solution.blocks):
                 vals: Dict[int, object] = {}
                 stored: Dict[int, Sched] = {}
@@ -364,8 +509,8 @@ def emit_stitched_fusion(
                         entry = pplan.entries.get(m.id)
                         if entry is not None and entry.action in (ALLOC, SHARE):
                             ref = scratch[off + entry.slot]
-                            ref[...] = v
-                            v = ref[...]
+                            _store(ref, v)
+                            v = _load(ref, v.shape)
                     vals[m.id] = v
                     stored[m.id] = sched
                     if m.id in iface_slot:
@@ -380,13 +525,10 @@ def emit_stitched_fusion(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
+        compiler_params=_compiler_params(plan.vmem_need),
     )
-
-    def fn(*args):
-        outs = call(*args)
-        return outs if isinstance(outs, (list, tuple)) else (outs,)
-
     return StitchedKernel(
-        fusion, None, plan, fn, inputs, roots, stitched=stitched
+        fusion, None, plan, _boundary(call, inputs, roots), inputs, roots,
+        stitched=stitched,
     )

@@ -10,14 +10,15 @@ returns a ``CompiledModule`` wrapping the planned executable and stats.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from .codegen import StitchedKernel
+from .codegen import StitchedKernel, resolve_interpret
 from .executor import StitchedExecutable
 from .fusion import FusionPlan
+from .memory import SCOPED_VMEM_BYTES
 from .perf_library import PerfLibrary
 from .pipeline import CompilationState, default_pipeline
 from .signature import KernelCache
@@ -27,7 +28,8 @@ from .xla_baseline import xla_baseline_kernel_count
 @dataclass
 class StitchOptions:
     fuse_dot: bool = True                    # user decision (paper §2.1)
-    vmem_limit: int = 4 * 1024 * 1024        # scratch budget per kernel
+    # VMEM budget per kernel: double-buffered I/O blocks + scratch
+    vmem_limit: int = SCOPED_VMEM_BYTES
     replicate_limit: int = 512 * 1024
     max_blocks: int = 4096
     ew_footprint_limit: int = 64 * 1024 * 1024
@@ -35,7 +37,9 @@ class StitchOptions:
     perf_library_path: Optional[str] = None
     kernel_cache_path: Optional[str] = None  # persistent tuning records
     dedup_kernels: bool = True               # fusion-signature kernel reuse
-    interpret: bool = True                   # CPU validation; False on TPU
+    # Pallas interpreter: None = exactly when the backend is not a TPU
+    # (resolved at compile time); True forces it, False forbids it.
+    interpret: Optional[bool] = None
     # "cost": candidate-plan exploration under the shared LatencyModel with
     # the greedy result as the floor; "greedy": the paper's Algorithm 1.
     planner: str = "cost"
@@ -231,6 +235,8 @@ class CompileStats:
     verify_boundaries: int = 0
     verify_warnings: int = 0
     verify_time_s: float = 0.0
+    # whether the kernels run in the Pallas interpreter (resolved option)
+    interpret: bool = True
 
     @property
     def replay_dispatch_reduction(self) -> int:
@@ -490,7 +496,14 @@ def build_outputs(state: CompilationState) -> None:
         collective_time_s=collective_time,
         collective_breaks_spanned=breaks_spanned,
         sharded_instrs=state.shard_stats.get("sharded_instrs", 0),
+        interpret=state.options.interpret,
     )
+
+
+def resolve_options(opts: StitchOptions) -> StitchOptions:
+    """``opts`` with ``interpret`` resolved against the running backend."""
+    interpret = resolve_interpret(opts.interpret)
+    return opts if opts.interpret is interpret else replace(opts, interpret=interpret)
 
 
 def compile_module(
@@ -523,7 +536,7 @@ def compile_module(
     (name, size) shape must match ``options.mesh_axes`` — the hashable half
     that salts every cache key.
     """
-    opts = options or StitchOptions()
+    opts = resolve_options(options or StitchOptions())
     t0 = time.perf_counter()
     library = PerfLibrary(opts.perf_library_path)
     store = measured_store

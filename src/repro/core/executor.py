@@ -32,13 +32,15 @@ still holds them).  The eager loop is kept as the replay oracle, and
 """
 from __future__ import annotations
 
+import functools
 import warnings
 from collections import deque
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .codegen import StitchedKernel
 from .fusion import FusionPlan, constant_like
@@ -134,15 +136,40 @@ class _KernelStep:
 
 
 class _OpStep:
-    """One standalone instruction (library dot etc.), pre-bound."""
+    """One standalone instruction (library dot etc.), pre-bound.  ``fn``
+    evaluates it on the values of ``arg_slots``."""
 
-    __slots__ = ("instr", "arg_slots", "out_slot", "release")
+    __slots__ = ("instr", "arg_slots", "out_slot", "release", "fn")
 
-    def __init__(self, instr: Instruction, arg_slots, out_slot):
+    def __init__(self, instr: Instruction, arg_slots, out_slot, fn=None):
         self.instr = instr
         self.arg_slots = arg_slots
         self.out_slot = out_slot
         self.release: List[int] = []
+        self.fn = fn or functools.partial(apply_op, instr)
+
+
+def _is_transpose_2d(instr: Instruction) -> bool:
+    return (
+        instr.opcode == "transpose"
+        and len(instr.shape) == 2
+        and tuple(instr.attrs["perm"]) == (1, 0)
+    )
+
+
+def _folded_dot(instr: Instruction, lhs_t: bool, rhs_t: bool) -> Callable:
+    """A 2-D ``dot`` that reads the operands of its standalone transposes:
+    the transposes become contracting dims, the form XLA gives a
+    transposed dot operand (and so its accumulation order)."""
+    pref = jnp.float32 if np.dtype(instr.dtype) == np.float32 else None
+    dims = (((0 if lhs_t else 1,), (1 if rhs_t else 0,)), ((), ()))
+
+    def fn(lhs, rhs):
+        return jax.lax.dot_general(
+            lhs, rhs, dims, preferred_element_type=pref
+        ).astype(instr.dtype)
+
+    return fn
 
 
 def _step_outs(step) -> List[int]:
@@ -336,7 +363,7 @@ class _JitSegment:
                         local[s] = o
                 else:
                     local[step.out_slot] = jax.lax.optimization_barrier(
-                        apply_op(step.instr, *args)
+                        step.fn(*args)
                     )
             return tuple(local[s] for s in out_slots)
 
@@ -409,12 +436,26 @@ class ExecutionPlan:
                     template_fill.append((new_slot(instr.id), fold(instr)))
 
         # ---- pre-bound steps in unit order ---------------------------------
+        # A standalone 2-D transpose read only by standalone 2-D dots runs
+        # no step of its own: the dots read its operand (``_folded_dot``).
+        standalone_ids = {s.id for s in plan.standalone}
+        root_ids = {r.id for r in module.roots}
+
+        def is_2d_dot(i: Instruction) -> bool:
+            return i.opcode == "dot" and all(len(o.shape) == 2 for o in i.operands)
+
+        folded = {
+            u.id for u in plan.standalone
+            if _is_transpose_2d(u) and u.id not in root_ids and u.users
+            and all(d.id in standalone_ids and is_2d_dot(d) for d in u.users)
+        }
         self.steps: List[object] = []
         for u in units:
             if isinstance(u, Instruction):
-                if u.opcode == "get":
-                    continue   # its slot is created by the call's loop step
-                arg_slots = [slot_of[o.id] for o in u.operands]
+                if u.opcode == "get" or u.id in folded:
+                    continue   # a get's slot is created by the call's loop step
+                srcs = [o.operands[0] if o.id in folded else o for o in u.operands]
+                arg_slots = [slot_of[o.id] for o in srcs]
                 if u.opcode == "call":
                     gets = sorted(
                         (g for g in u.users if g.opcode == "get"),
@@ -441,7 +482,11 @@ class ExecutionPlan:
                         )
                     )
                 else:
-                    self.steps.append(_OpStep(u, arg_slots, new_slot(u.id)))
+                    fn = None
+                    if any(o.id in folded for o in u.operands):
+                        lhs, rhs = u.operands
+                        fn = _folded_dot(u, lhs.id in folded, rhs.id in folded)
+                    self.steps.append(_OpStep(u, arg_slots, new_slot(u.id), fn))
             else:
                 k = kernels[u.name]
                 arg_slots = [slot_of[i.id] for i in k.inputs]
@@ -567,7 +612,7 @@ class ExecutionPlan:
                     buf[s] = o
             else:
                 buf[step.out_slot] = jax.lax.optimization_barrier(
-                    apply_op(step.instr, *args)
+                    step.fn(*args)
                 )
             for s in step.release:
                 buf[s] = None
@@ -603,9 +648,7 @@ class ExecutionPlan:
                 for s, o in zip(step.out_slots, outs, strict=False):
                     buf[s] = o
             else:
-                buf[step.out_slot] = apply_op(
-                    step.instr, *[buf[s] for s in step.arg_slots]
-                )
+                buf[step.out_slot] = step.fn(*[buf[s] for s in step.arg_slots])
             for s in step.release:
                 buf[s] = None
         self.stats.eager_calls += 1

@@ -44,11 +44,11 @@ the horizontal-merge post-pass.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Set, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 from .ir import Instruction, Module
 from .latency import LatencyModel
-from .memory import MemoryInfeasible, plan_memory, plan_stitched_memory
+from .memory import SCOPED_VMEM_BYTES, MemoryInfeasible, plan_memory, plan_stitched_memory
 from .schedule import CONSISTENT, STITCHABLE, StitchVerdict, stitchable
 from . import span as span_lib
 
@@ -270,7 +270,7 @@ class FusionScorer:
         model: Optional[LatencyModel] = None,
         replicate_limit: int = 512 * 1024,
         max_blocks: int = 4096,
-        vmem_limit: int = 4 * 1024 * 1024,
+        vmem_limit: int = SCOPED_VMEM_BYTES,
         allow_stitch: bool = True,
         stitch_replicate_limit: Optional[int] = None,
         stitch_max_blocks: int = 64,
@@ -420,23 +420,40 @@ def _elementwise_groups(
     return [g for g in groups if len(g) >= 2]
 
 
-def _would_cycle(hlo: Instruction, fused: Set[Instruction]) -> bool:
-    """True if fusing ``hlo`` creates a group-level dependence cycle: a path
-    from ``hlo`` through outside-the-fusion consumers back to an input of the
-    fusion.  (The paper collapses fusions into single HLO instructions after
-    each pass, which makes such cycles visible structurally; with virtual
-    groups we check reachability explicitly.)"""
-    stack = [u for u in hlo.users if u not in fused]
+#: instruction id -> members of the committed fusion that holds it
+GroupMap = Dict[int, Sequence[Instruction]]
+
+
+def _reenters(
+    srcs: Sequence[Instruction], fused: Set[Instruction], groups: GroupMap
+) -> bool:
+    """Whether a path from ``srcs`` through instructions outside ``fused``
+    reaches ``fused``.  A committed fusion in ``groups`` runs as one kernel,
+    so a path that enters it leaves through all of its members."""
+    stack = [u for s in srcs for u in s.users if u not in fused]
     seen: Set[int] = set()
     while stack:
         n = stack.pop()
         if n.id in seen:
             continue
-        seen.add(n.id)
-        if any(u in fused for u in n.users):
-            return True
-        stack.extend(u for u in n.users if u not in fused)
+        for src in groups.get(n.id, (n,)):
+            seen.add(src.id)
+            for u in src.users:
+                if u in fused:
+                    return True
+                stack.append(u)
     return False
+
+
+def _would_cycle(
+    hlo: Instruction, fused: Set[Instruction], groups: GroupMap
+) -> bool:
+    """True if fusing ``hlo`` creates a group-level dependence cycle: a path
+    from ``hlo`` through outside-the-fusion consumers back to an input of the
+    fusion.  (The paper collapses fusions into single HLO instructions after
+    each pass, which makes such cycles visible structurally; with virtual
+    groups we check reachability explicitly.)"""
+    return _reenters([hlo], fused, groups)
 
 
 def subgraph_fuse(
@@ -447,8 +464,10 @@ def subgraph_fuse(
     roof: int,
     assigned: Set[int],
     cfg: FusionConfig,
+    groups: GroupMap,
 ) -> List[Instruction]:
-    """Algorithm 1: fuse producers layer-by-layer from the seed up to roof."""
+    """Algorithm 1: fuse producers layer-by-layer from the seed up to roof.
+    ``groups`` are the fusions committed so far (see ``_reenters``)."""
     fused: Set[Instruction] = set(seed)
     giveup: Set[Instruction] = set()
     roots = list(seed)
@@ -470,7 +489,7 @@ def subgraph_fuse(
                 continue
             if not any(u in fused for u in hlo.users):
                 continue                   # producer/consumer fusion only
-            if _would_cycle(hlo, fused):
+            if _would_cycle(hlo, fused, groups):
                 giveup.add(hlo)
                 continue
             tentative = _topo_sorted(fused | {hlo}, module)
@@ -647,6 +666,7 @@ def _choose_pack(
     scorer: FusionScorer,
     cfg: FusionConfig,
     stats: PlannerStats,
+    committed: GroupMap,
 ) -> Tuple[List[List[Instruction]], List[Optional[float]]]:
     """Commit a sink-pack group: either the union of all towers as ONE
     kernel, or each tower's own best partition (the greedy floor)."""
@@ -662,7 +682,7 @@ def _choose_pack(
     union = set()
     for t in towers:
         union.update(t)
-    if _group_cycle(union):
+    if _group_cycle(union, committed):
         return groups, costs
     packed = _topo_sorted(union, module)
     if len(packed) > cfg.max_fusion_ops:
@@ -685,20 +705,16 @@ def _choose_pack(
     return groups, costs
 
 
-def _group_cycle(fused: Set[Instruction]) -> bool:
-    """Would the member union reach itself through outside instructions?"""
-    stack = [u for m in fused for u in m.users if u not in fused]
-    seen: Set[int] = set()
-    while stack:
-        n = stack.pop()
-        if n.id in seen:
-            continue
-        seen.add(n.id)
-        for u in n.users:
-            if u in fused:
-                return True
-            stack.append(u)
-    return False
+def _group_cycle(
+    fused: Set[Instruction], groups: Optional[GroupMap] = None
+) -> bool:
+    """Would the member union reach itself through outside instructions
+    (and through the committed fusions in ``groups``)?"""
+    return _reenters(list(fused), fused, groups or {})
+
+
+def _group_map(fusions: Sequence[Optional[FusedComputation]]) -> GroupMap:
+    return {m.id: f.members for f in fusions if f is not None for m in f.members}
 
 
 def _merge_key(f: FusedComputation) -> tuple:
@@ -750,7 +766,7 @@ def _horizontal_merge(
                     ):
                         continue
                     union = set(a.members) | set(b.members)
-                    if _group_cycle(union):
+                    if _group_cycle(union, _group_map(fusions)):
                         continue
                     merged_members = _topo_sorted(union, module)
                     stats.plans_explored += 1
@@ -797,8 +813,13 @@ def deep_fuse(module: Module, cfg: Optional[FusionConfig] = None) -> FusionPlan:
 
     assigned: Set[int] = set()
     fusions: List[FusedComputation] = []
+    committed: GroupMap = {}
     forced_standalone: List[Instruction] = []
     greedy_fusion_count = 0      # kernels the pure-greedy plan would emit
+
+    def commit(g: List[Instruction], cost: Optional[float]) -> None:
+        fusions.append(_commit_fusion(g, f"f{len(fusions)}", cost, scorer))
+        committed.update((m.id, fusions[-1].members) for m in g)
 
     for root_span in range(0, max_span + 1):
         layer = layer_map.get(root_span, [])
@@ -825,7 +846,13 @@ def deep_fuse(module: Module, cfg: Optional[FusionConfig] = None) -> FusionPlan:
                 continue
             seeds.append([instr])
 
-        for seed in seeds:
+        while seeds:
+            seed = seeds.pop(0)
+            if len(seed) > 1 and _group_cycle(set(seed), committed):
+                # the horizontal group would reach itself through a fusion
+                # committed in a lower layer: seed its members one by one
+                seeds[:0] = [[s] for s in seed]
+                continue
             if not cfg.consistency(seed, seed):
                 # even the seed alone has no valid schedule — leave standalone
                 for s in seed:
@@ -833,16 +860,14 @@ def deep_fuse(module: Module, cfg: Optional[FusionConfig] = None) -> FusionPlan:
                     forced_standalone.append(s)
                 continue
             members = subgraph_fuse(
-                seed, module, span, layer_map, roof, assigned, cfg
+                seed, module, span, layer_map, roof, assigned, cfg, committed
             )
             for m in members:
                 assigned.add(m.id)
             greedy_fusion_count += 1
             groups, costs = _choose_partition(members, scorer, cfg, stats)
             for g, c in zip(groups, costs, strict=False):
-                fusions.append(
-                    _commit_fusion(g, f"f{len(fusions)}", c, scorer)
-                )
+                commit(g, c)
 
         # -- step 3: sink-pack groups — grow each tower exactly as greedy
         # would (one seed per sink), then score the union as ONE kernel
@@ -854,7 +879,8 @@ def deep_fuse(module: Module, cfg: Optional[FusionConfig] = None) -> FusionPlan:
                     forced_standalone.append(sink)
                     continue
                 t = subgraph_fuse(
-                    [sink], module, span, layer_map, roof, assigned, cfg
+                    [sink], module, span, layer_map, roof, assigned, cfg,
+                    committed,
                 )
                 for m in t:
                     assigned.add(m.id)
@@ -862,11 +888,11 @@ def deep_fuse(module: Module, cfg: Optional[FusionConfig] = None) -> FusionPlan:
                 greedy_fusion_count += 1
             if not towers:
                 continue
-            groups, costs = _choose_pack(towers, module, scorer, cfg, stats)
+            groups, costs = _choose_pack(
+                towers, module, scorer, cfg, stats, committed
+            )
             for g, c in zip(groups, costs, strict=False):
-                fusions.append(
-                    _commit_fusion(g, f"f{len(fusions)}", c, scorer)
-                )
+                commit(g, c)
 
     # --- horizontal-merge post-pass (cost mode only) ---------------------
     if scorer is not None:

@@ -35,8 +35,9 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, fields
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from .ir import Instruction
@@ -85,6 +86,29 @@ class DeviceSpec:
 
 
 TPU_V5E = DeviceSpec()
+
+#: DeviceSpec by ``device_kind`` as JAX reports it.  Peaks of one v5e chip:
+#: Google Cloud documentation, "TPU v5e".
+DEVICE_SPECS = {"TPU v5 lite": TPU_V5E}
+
+
+def device_spec(device=None) -> DeviceSpec:
+    """The DeviceSpec of ``device`` (default: the first JAX device).
+
+    The CPU backend plans for ``TPU_V5E``, the chip this repo targets; any
+    other device kind missing from ``DEVICE_SPECS`` is an error, never a
+    silent v5e.
+    """
+    dev = device if device is not None else jax.devices()[0]
+    if dev.platform == "cpu":
+        return TPU_V5E
+    try:
+        return DEVICE_SPECS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no DeviceSpec for device kind {dev.device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_SPECS)}"
+        ) from None
 
 # VPU op weight: how many vector-op equivalents one element costs.
 _EW_WEIGHT = {"add": 1, "sub": 1, "mul": 1, "max": 1, "min": 1, "neg": 1,
@@ -154,8 +178,8 @@ class LatencyModel:
     terms — all against the same ``DeviceSpec``.
     """
 
-    def __init__(self, spec: DeviceSpec = TPU_V5E):
-        self.spec = spec
+    def __init__(self, spec: Optional[DeviceSpec] = None):
+        self.spec = spec if spec is not None else device_spec()
 
     # ---- per-op (the PerfLibrary miss handler, paper §4.4) ---------------
     def peak_for(self, instr: Instruction) -> float:

@@ -43,11 +43,11 @@ import numpy as np
 
 import jax
 
-from .codegen import StitchedKernel, emit_fusion, emit_stitched_fusion
+from .codegen import StitchedKernel, emit_fusion, emit_stitched_fusion, resolve_interpret
 from .fusion import FusedComputation
 from .ir import Instruction
-from .latency import TPU_V5E, DeviceSpec
-from .memory import MemoryInfeasible, plan_memory, plan_stitched_memory
+from .latency import DeviceSpec, device_spec
+from .memory import SCOPED_VMEM_BYTES, MemoryInfeasible, plan_memory, plan_stitched_memory
 from .perf_library import JsonStore, PerfLibrary
 from .schedule import resolve_stitched
 from .tuning import tune
@@ -59,18 +59,19 @@ MEASURE_SCHEMA_VERSION = 1
 
 
 def device_fingerprint(
-    spec: DeviceSpec = TPU_V5E, interpret: bool = True
+    spec: Optional[DeviceSpec] = None, interpret: Optional[bool] = None
 ) -> str:
     """Fingerprint of the measurement substrate: the DeviceSpec constants
     plus the runtime backend actually executing kernels (platform + device
-    kind + interpret flag).  Interpret-mode CPU timings must never serve a
-    real-TPU compile and vice versa — they describe different machines."""
+    kind + interpret flag, both resolved from the backend when None).
+    Interpret-mode CPU timings must never serve a real-TPU compile and vice
+    versa — they describe different machines."""
     dev = jax.devices()[0]
     feats = (
-        spec.fingerprint(),
+        (spec or device_spec(dev)).fingerprint(),
         jax.default_backend(),
         getattr(dev, "device_kind", "unknown"),
-        bool(interpret),
+        resolve_interpret(interpret),
     )
     return hashlib.sha256(repr(feats).encode()).hexdigest()[:16]
 
@@ -231,12 +232,12 @@ def emit_group(
     members: List[Instruction],
     library: Optional[PerfLibrary] = None,
     *,
-    vmem_limit: int = 4 * 1024 * 1024,
+    vmem_limit: int = SCOPED_VMEM_BYTES,
     replicate_limit: int = 512 * 1024,
     max_blocks: int = 4096,
     stitch_replicate_limit: Optional[int] = None,
     stitch_max_blocks: int = 64,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Optional[StitchedKernel]:
     """Compile ``members`` as ONE kernel through the production path: §4.3
     schedule tuning + §5.1 memory planning + §5.2 emission, falling back to

@@ -17,6 +17,10 @@ Three phases, faithfully ported from GPU shared memory to TPU VMEM scratch:
      ``MemoryInfeasible`` propagates back to the fusion pass
      (ScheduleConsistencyChecker feedback).
 
+  Kernel inputs and outputs are VMEM blocks too, and Pallas double-buffers
+  each one to overlap the HBM copies with compute: the budget covers those
+  blocks twice plus the scratch, each at its tiled (padded) size.
+
   3. **Space sharing** (§5.1.3): build a dominance tree from the root
      (Cooper-Harvey-Kennedy on the reverse dataflow graph) and let an op
      reuse a buffer whose owner it dominates — by then the owner's value is
@@ -32,7 +36,11 @@ from typing import Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from .ir import Instruction
-from .schedule import ScheduleSolution, StitchedSolution, chunk_shape
+from .schedule import LANES, ScheduleSolution, StitchedSolution, chunk_shape, sublanes
+
+#: Scoped VMEM a v5e kernel gets unless it asks for more — the default
+#: per-kernel budget (inputs/outputs double-buffered + scratch).
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 ALLOC = "ALLOC"
 SHARE = "SHARE"
@@ -41,6 +49,32 @@ INLINE = "INLINE"
 
 class MemoryInfeasible(Exception):
     """Required buffers exceed the VMEM budget — feedback to fusion."""
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
+
+def vmem_bytes(shape: Tuple[int, ...], dtype) -> int:
+    """Bytes a VMEM buffer of ``shape`` takes once padded to the tiling
+    (rank < 2 lays out as one row, rank 0 as a (1, 1) block)."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
+    lead = int(np.prod(shape[:-2], dtype=np.int64))
+    return (
+        lead * _round_up(shape[-2], sublanes(dtype)) * _round_up(shape[-1], LANES)
+        * np.dtype(dtype).itemsize
+    )
+
+
+def io_blocks(members: List[Instruction], roots: List[Instruction]) -> List[Instruction]:
+    """The fusion's kernel inputs (operands from outside) and outputs, once each."""
+    member_ids = {m.id for m in members}
+    out, seen = [], set()
+    for i in [o for m in members for o in m.operands if o.id not in member_ids] + list(roots):
+        if i.id not in seen:
+            seen.add(i.id)
+            out.append(i)
+    return out
 
 
 @dataclass
@@ -60,10 +94,16 @@ class MemoryPlan:
     total_bytes: int
     shared_bytes: int
     shrunk: List[str] = field(default_factory=list)
+    io_bytes: int = 0        # double-buffered input/output blocks, padded
 
     @property
     def num_shrinks(self) -> int:
         return len(self.shrunk)
+
+    @property
+    def vmem_need(self) -> int:
+        """Padded VMEM the kernel allocates: I/O blocks + scratch slots."""
+        return self.io_bytes + sum(vmem_bytes(s, d) for s, d in self.slots)
 
     @property
     def shared_ratio(self) -> float:
@@ -167,10 +207,19 @@ def plan_memory(
     members: List[Instruction],
     roots: List[Instruction],
     solution: ScheduleSolution,
-    vmem_limit: int = 4 * 1024 * 1024,
+    vmem_limit: int = SCOPED_VMEM_BYTES,
+    count_io: bool = True,
 ) -> MemoryPlan:
+    """``count_io=False`` plans scratch alone — for the phases of a stitched
+    kernel, whose I/O blocks ``plan_stitched_memory`` counts whole."""
     member_ids = {m.id for m in members}
     root_ids = {r.id for r in roots}
+    io_bytes = 0
+    if count_io:
+        io_bytes = 2 * sum(
+            vmem_bytes(chunk_shape(*solution.block(i)), i.dtype)
+            for i in io_blocks(members, roots)
+        )
 
     # ---- phase 1: size requirements (candidates) -------------------------
     # category: 0=required, 1=cheap multi-user, 2=expensive multi-user,
@@ -192,24 +241,27 @@ def plan_memory(
                 candidates[m.id] = 1
 
     sizes: Dict[int, Tuple[Tuple[int, ...], int]] = {}
+    padded: Dict[int, int] = {}
     for m in members:
         if m.id in candidates:
             cs = chunk_shape(m.shape, solution.sched(m))
             nbytes = int(np.prod(cs, dtype=np.int64)) * np.dtype(m.dtype).itemsize
             sizes[m.id] = (tuple(cs), nbytes)
+            padded[m.id] = vmem_bytes(cs, m.dtype)
 
     # ---- phase 2: size shrinking -----------------------------------------
     span_rank = {m.id: i for i, m in enumerate(members)}  # later = closer root
     shrunk: List[str] = []
 
     def demand() -> int:
-        return sum(sizes[i][1] for i in candidates)
+        return io_bytes + sum(padded[i] for i in candidates)
 
     while demand() > vmem_limit:
         droppable = [i for i, cat in candidates.items() if cat > 0]
         if not droppable:
             raise MemoryInfeasible(
-                f"required buffers need {demand()}B > {vmem_limit}B budget"
+                f"required buffers + I/O blocks need {demand()}B > "
+                f"{vmem_limit}B budget"
             )
         # paper order: category 1, then 2, then 3; within a category the
         # op closest to the root goes first.
@@ -267,7 +319,7 @@ def plan_memory(
         if m.id not in entries:
             entries[m.id] = BufferEntry(INLINE)
 
-    return MemoryPlan(entries, slots, total, shared, shrunk)
+    return MemoryPlan(entries, slots, total, shared, shrunk, io_bytes)
 
 
 # --------------------------------------------------------------------------
@@ -308,11 +360,21 @@ class StitchedMemoryPlan:
     interfaces: Dict[int, InterfaceBuffer]     # instr id -> staged buffer
     phase_plans: List[MemoryPlan]
     interface_bytes: int
-    io_bytes: int = 0        # whole-tensor input/output blocks (trivial grid)
+    io_bytes: int = 0        # whole-tensor I/O blocks, double-buffered, padded
 
     @property
     def num_phases(self) -> int:
         return len(self.phase_plans)
+
+    @property
+    def vmem_need(self) -> int:
+        """Padded VMEM the kernel allocates: whole I/O blocks, staged
+        interfaces and every phase's scratch slots."""
+        return (
+            self.io_bytes
+            + sum(vmem_bytes(b.shape, b.dtype) for b in self.interfaces.values())
+            + sum(p.vmem_need for p in self.phase_plans)
+        )
 
     # ---- MemoryPlan-compatible reporting surface -------------------------
     @property
@@ -336,7 +398,7 @@ class StitchedMemoryPlan:
 
 def plan_stitched_memory(
     stitched: StitchedSolution,
-    vmem_limit: int = 4 * 1024 * 1024,
+    vmem_limit: int = SCOPED_VMEM_BYTES,
 ) -> StitchedMemoryPlan:
     """Plan VMEM for a stitched kernel: one full-size staging buffer per
     interface tensor plus one chunk-granular plan per phase, checked against
@@ -368,35 +430,31 @@ def plan_stitched_memory(
     # resident for the entire kernel too (unlike the chunk-sized blocks of a
     # schedule-consistent kernel) — they must come out of the same budget
     group_ids = set(phase_of)
-    io_bytes = 0
-    seen_io = set()
-    for p in stitched.phases:
-        for m in p.members:
-            for o in m.operands:
-                if o.id not in group_ids and o.id not in seen_io:
-                    seen_io.add(o.id)
-                    io_bytes += int(o.bytesize)
-            if m.id not in seen_io and (
-                not m.users or any(u.id not in group_ids for u in m.users)
-            ):
-                seen_io.add(m.id)
-                io_bytes += int(m.bytesize)
+    members = [m for p in stitched.phases for m in p.members]
+    group_roots = [
+        m for m in members
+        if not m.users or any(u.id not in group_ids for u in m.users)
+    ]
+    io_bytes = 2 * sum(
+        vmem_bytes(i.shape, i.dtype) for i in io_blocks(members, group_roots)
+    )
 
     iface_bytes = sum(b.nbytes for b in interfaces.values())
-    if iface_bytes + io_bytes > vmem_limit:
+    iface_vmem = sum(vmem_bytes(b.shape, b.dtype) for b in interfaces.values())
+    if iface_vmem + io_bytes > vmem_limit:
         raise MemoryInfeasible(
-            f"staged interfaces ({iface_bytes}B) + whole-tensor kernel I/O "
+            f"staged interfaces ({iface_vmem}B) + whole-tensor kernel I/O "
             f"({io_bytes}B) > {vmem_limit}B budget"
         )
     phase_plans: List[MemoryPlan] = []
-    remaining = vmem_limit - iface_bytes - io_bytes
+    remaining = vmem_limit - iface_vmem - io_bytes
     for p in stitched.phases:
         # every phase's slots coexist with the interfaces and with every
         # other phase's slots for the whole kernel, so each phase plans
         # (and shrinks) against what earlier phases left over; a phase
         # whose REQUIRED buffers exceed that raises MemoryInfeasible
-        plan = plan_memory(p.members, p.roots, p.solution, remaining)
+        plan = plan_memory(p.members, p.roots, p.solution, remaining, count_io=False)
         phase_plans.append(plan)
-        remaining -= plan.total_bytes
+        remaining -= sum(vmem_bytes(shape, dt) for shape, dt in plan.slots)
 
     return StitchedMemoryPlan(interfaces, phase_plans, iface_bytes, io_bytes)

@@ -24,7 +24,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from .codegen import StitchedKernel, emit_fusion, emit_stitched_fusion
+from .codegen import StitchedKernel, emit_fusion, emit_stitched_fusion, resolve_interpret
 from .fusion import (
     FusedComputation,
     FusionConfig,
@@ -347,7 +347,7 @@ def _measure_salt(opts) -> str:
     must still serve a later read-only ``tuning_store_path`` compile."""
     srl = _stitch_replicate_limit(opts)
     salt = (
-        f"i{int(opts.interpret)}:v{opts.vmem_limit}:r{opts.replicate_limit}"
+        f"i{int(resolve_interpret(opts.interpret))}:v{opts.vmem_limit}:r{opts.replicate_limit}"
         f":b{opts.max_blocks}:p{opts.planner}"
         f":st{int(opts.enable_stitching)}:sb{opts.stitch_max_blocks}:sr{srl}:"
     )

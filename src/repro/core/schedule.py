@@ -22,12 +22,17 @@ needs that the paper leaves implicit:
   * alignment — all *chunked* instructions in a fusion must agree on the
     launch ``blocks``; propagation fails (or falls back to Replicated) when
     an op's own blocks formula cannot match the launch grid.
+  * tiling — every block a kernel reads or writes must be one the TPU's
+    (sublane, lane) tiling can address (``tile_legal``), and no block may
+    exceed ``replicate_limit``; a solution that breaks either is
+    Unsatisfiable, so no mode ever plans a kernel the chip would refuse.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
 
 from .ir import COLLECTIVE_OPCODES, Instruction
 
@@ -111,6 +116,42 @@ def block_index(shape: Tuple[int, ...], sched: Sched, b):
     return tuple(idx)
 
 
+LANES = 128
+
+
+def sublanes(dtype) -> int:
+    """Sublane tile of ``dtype``: 8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit."""
+    return 8 * max(1, 4 // np.dtype(dtype).itemsize)
+
+
+def tile_legal(shape: Tuple[int, ...], chunk: Tuple[int, ...], dtype=np.float32) -> bool:
+    """Whether Mosaic accepts ``chunk`` as a block of an array of ``shape``.
+
+    The last two block dims must each equal the array's or be a multiple of
+    the (sublane, lane) tile; a rank-1 block must be whole or a multiple of
+    128.  Leading dims are free.
+    """
+    shape, chunk = tuple(shape), tuple(chunk)
+    if chunk == shape:
+        return True
+    if len(shape) == 1:
+        return chunk[0] % LANES == 0
+    lane_ok = chunk[-1] == shape[-1] or chunk[-1] % LANES == 0
+    sub_ok = chunk[-2] == shape[-2] or chunk[-2] % sublanes(dtype) == 0
+    return lane_ok and sub_ok
+
+
+def reshape_legal(src: Tuple[int, ...], dst: Tuple[int, ...]) -> bool:
+    """Whether Mosaic can reshape a ``src`` tile into ``dst`` in a kernel:
+    the lane dim stays, and the sublane dim stays or both sides keep whole
+    8-row tiles (leading dims are free)."""
+    s2 = (1, 1)[len(src):] + tuple(src)
+    d2 = (1, 1)[len(dst):] + tuple(dst)
+    if s2 == d2:
+        return True
+    return s2[-1] == d2[-1] and (s2[-2] == d2[-2] or (s2[-2] % 8 == 0 and d2[-2] % 8 == 0))
+
+
 def _divisors(n: int, cap: int = 24) -> List[int]:
     ds = [d for d in range(1, int(n ** 0.5) + 1) if n % d == 0]
     ds = sorted(set(ds + [n // d for d in ds]))
@@ -125,9 +166,11 @@ def _divisors(n: int, cap: int = 24) -> List[int]:
 
 
 def candidate_schedules(shape: Tuple[int, ...], max_blocks: int = 1 << 16) -> List[Sched]:
-    """The (small) schedule space of one output shape — paper §4.1."""
+    """The (small) schedule space of one output shape — paper §4.1 — cut to
+    the schedules whose blocks the 32-bit tiling can address (the tighter
+    16/8-bit rule is checked when a solution is resolved)."""
     if not shape:
-        return [Sched(split_dim=0, sword=1, sched_type=ROW)] if False else [REPLICATED]
+        return [REPLICATED]
     out, seen = [], set()
     for s in range(len(shape)):
         for w in _divisors(shape[s]):
@@ -137,7 +180,7 @@ def candidate_schedules(shape: Tuple[int, ...], max_blocks: int = 1 << 16) -> Li
                 if b > max_blocks:
                     continue
                 key = (b, chunk_shape(shape, sched))
-                if key in seen:
+                if key in seen or not tile_legal(shape, key[1]):
                     continue
                 seen.add(key)
                 out.append(sched)
@@ -277,9 +320,19 @@ class ScheduleSolution:
     blocks: int
     assignment: Dict[int, Sched]          # instr id -> Sched (members + inputs)
     root_scheds: Dict[int, Sched]
+    # reshape roots applied outside the kernel (see ``resolve_schedules``)
+    exits: frozenset = frozenset()
 
     def sched(self, instr: Instruction) -> Sched:
         return self.assignment[instr.id]
+
+    def block(self, instr: Instruction) -> Tuple[Tuple[int, ...], Sched]:
+        """(array shape, schedule) of the block the kernel reads or writes
+        for ``instr``: an exit reshape's kernel output is its operand."""
+        if instr.id in self.exits:
+            o = instr.operands[0]
+            return tuple(o.shape), propagate(instr, self.sched(instr))[0]
+        return tuple(instr.shape), self.sched(instr)
 
 
 def resolve_schedules(
@@ -287,12 +340,21 @@ def resolve_schedules(
     roots: List[Instruction],
     root_scheds: Dict[int, Sched],
     replicate_limit: int = 512 * 1024,
+    allow_exits: bool = True,
 ) -> ScheduleSolution:
     """Back-propagate root schedules through the fusion (paper §4.2).
 
     ``members`` must be topologically ordered.  All chunked instructions are
     checked to agree on the launch ``blocks``.  Conflicting requirements fall
-    back to Replicated when the tensor fits ``replicate_limit``.
+    back to Replicated when the tensor fits ``replicate_limit``.  Every
+    kernel block (roots and inputs from outside the fusion) must be
+    ``tile_legal`` and fit ``replicate_limit``.
+
+    A reshape the tiling cannot do in a kernel is Unsatisfiable, except at
+    the exit (``allow_exits``): a reshape root with no user in ``members``
+    is applied to the kernel's output outside it, where a row-major reshape
+    is free.  Phases of a stitched kernel pass ``allow_exits=False``: their
+    roots may feed later phases.
     """
     member_ids = {m.id for m in members}
     launch_blocks = None
@@ -354,7 +416,39 @@ def resolve_schedules(
                     f"{instr.name}: operand {o.name} has {got}, needs {osched}"
                 )
 
-    return ScheduleSolution(launch_blocks, assignment, dict(root_scheds))
+    root_ids = {r.id for r in roots}
+    exits = set()
+    for instr in members:
+        if instr.opcode in ("reshape", "bitcast"):
+            needed = propagate(instr, assignment[instr.id])[0]
+            src = chunk_shape(instr.operands[0].shape, needed)
+            dst = chunk_shape(instr.shape, assignment[instr.id])
+            if src == dst or reshape_legal(src, dst):
+                continue
+            if (
+                allow_exits and instr.id in root_ids
+                and not any(u.id in member_ids for u in instr.users)
+            ):
+                exits.add(instr.id)
+                continue
+            raise Unsatisfiable(f"{instr.name}: in-kernel reshape {src}->{dst}")
+    sol = ScheduleSolution(
+        launch_blocks, assignment, dict(root_scheds), frozenset(exits)
+    )
+
+    boundary = list(roots) + [
+        o for m in members for o in m.operands if o.id not in member_ids
+    ]
+    for instr in boundary:
+        shape, sched = sol.block(instr)
+        if sched.kind == "replicated":
+            continue          # whole-array block: legal, and bounded above
+        cs = chunk_shape(shape, sched)
+        if not tile_legal(shape, cs, instr.dtype):
+            raise Unsatisfiable(f"{instr.name}: block {cs} breaks the tiling")
+        if int(np.prod(cs)) * np.dtype(instr.dtype).itemsize > replicate_limit:
+            raise Unsatisfiable(f"{instr.name}: block {cs} > limit")
+    return sol
 
 
 def any_satisfiable(
@@ -363,6 +457,7 @@ def any_satisfiable(
     candidates: Optional[List[Sched]] = None,
     replicate_limit: int = 512 * 1024,
     max_blocks: int = 1 << 16,
+    allow_exits: bool = True,
 ) -> Optional[ScheduleSolution]:
     """Cheap existence check used by SchdConsistent during fusion."""
     cands = candidates or candidate_schedules(roots[0].shape, max_blocks)
@@ -387,7 +482,9 @@ def any_satisfiable(
                     rs[r.id] = alt[0]
             if not ok:
                 continue
-            return resolve_schedules(members, roots, rs, replicate_limit)
+            return resolve_schedules(
+                members, roots, rs, replicate_limit, allow_exits
+            )
         except Unsatisfiable:
             continue
     return None
@@ -507,19 +604,22 @@ def _phase_solution(
     sol = any_satisfiable(
         phase_members, roots,
         replicate_limit=replicate_limit, max_blocks=max_blocks,
+        allow_exits=False,
     )
     if sol is not None:
         return sol, 0
     lim = max(stitch_replicate_limit, replicate_limit)
     sol = any_satisfiable(
-        phase_members, roots, replicate_limit=lim, max_blocks=max_blocks
+        phase_members, roots, replicate_limit=lim, max_blocks=max_blocks,
+        allow_exits=False,
     )
     if sol is not None:
         return sol, 1
     try:
         return (
             resolve_schedules(
-                phase_members, roots, {r.id: REPLICATED for r in roots}, lim
+                phase_members, roots, {r.id: REPLICATED for r in roots}, lim,
+                allow_exits=False,
             ),
             2,
         )

@@ -49,10 +49,12 @@ def spec_to_layout(spec, rank: int) -> Layout:
     return tuple(out)
 
 
-def names_to_layout(names: Dict[int, Sequence[str]], rank: int) -> Layout:
-    """shard_map ``in_names``/``out_names`` dict ({dim: axis names}) -> layout."""
+def pspec_to_layout(spec, rank: int) -> Layout:
+    """shard_map ``in_specs``/``out_specs`` entry (a PartitionSpec) -> layout."""
+    entries = list(spec) + [None] * (rank - len(spec))
     return tuple(
-        tuple(names[d]) if d in names and names[d] else None for d in range(rank)
+        None if not e else ((e,) if isinstance(e, str) else tuple(e))
+        for e in entries
     )
 
 

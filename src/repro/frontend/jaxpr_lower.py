@@ -98,7 +98,7 @@ IDENTITY_PRIMS = frozenset({"stop_gradient", "copy", "device_put"})
 #: call-like primitives whose inner jaxpr is inlined ("remat2" is the
 #: primitive jax.checkpoint/jax.remat actually emit)
 CALL_PRIMS = frozenset(
-    {"pjit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
+    {"jit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
      "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "remat", "remat2",
      "checkpoint"}
 )
@@ -847,7 +847,7 @@ class LoweredShardedJaxpr(LoweredJaxpr):
     placement the one multi-device ExecutionPlan replays under.
 
     ``param_layouts`` maps parameter names to ``core.shard`` layout tuples
-    (from the shard_map ``in_names``); ``out_layouts`` is one layout per
+    (from the shard_map ``in_specs``); ``out_layouts`` is one layout per
     module root, in ``module.roots`` order — exactly what
     ``compile_module(..., mesh=, param_layouts=, out_layouts=)`` takes.
     """
@@ -881,7 +881,7 @@ def lower_sharded_jaxpr(
     constants.  A constant operand that shard_map expects SHARDED has no
     global value to slice here and raises ``UnsupportedPrimitiveError``.
     """
-    from ..core.shard import mesh_axes_of, names_to_layout
+    from ..core.shard import mesh_axes_of, pspec_to_layout
 
     jaxpr = closed_jaxpr.jaxpr
     sm = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
@@ -894,8 +894,8 @@ def lower_sharded_jaxpr(
     eqn = sm[0]
     mesh = eqn.params["mesh"]
     inner = eqn.params["jaxpr"]          # raw per-shard Jaxpr (no constvars)
-    in_names = eqn.params["in_names"]
-    out_names_p = eqn.params["out_names"]
+    in_specs = eqn.params["in_specs"]
+    out_specs = eqn.params["out_specs"]
 
     outer_args = {v: i for i, v in enumerate(jaxpr.invars)}
     consts = dict(zip(jaxpr.constvars, closed_jaxpr.consts, strict=False))
@@ -928,7 +928,7 @@ def lower_sharded_jaxpr(
         atom = eqn.invars[k]
         ivar = inner.invars[k]
         rank = len(ivar.aval.shape)
-        layout = names_to_layout(in_names[k], rank)
+        layout = pspec_to_layout(in_specs[k], rank)
         if not isinstance(atom, Literal) and atom in outer_args:
             pname = param_names[outer_args[atom]]
             env[ivar] = b.parameter(
@@ -950,8 +950,8 @@ def lower_sharded_jaxpr(
     output_names = _finish_outputs(b, lw, env, inner.outvars)
 
     out_layout_by_name = {
-        oname: names_to_layout(names, len(ov.aval.shape))
-        for oname, ov, names in zip(output_names, inner.outvars, out_names_p, strict=False)
+        oname: pspec_to_layout(spec, len(ov.aval.shape))
+        for oname, ov, spec in zip(output_names, inner.outvars, out_specs, strict=False)
     }
     out_layouts = [
         out_layout_by_name.get(r.name) for r in b.module.roots

@@ -6,8 +6,9 @@
 On a real TPU fleet this binary runs once per host (jax.distributed
 initializes from the TPU environment); the mesh comes from
 ``make_production_mesh`` and every step is pjit-sharded by
-``repro.distributed.sharding``.  On CPU (``--reduced``) it trains a reduced
-config end-to-end with the identical code path minus the mesh.
+``repro.distributed.sharding``.  The config trains at its published width
+unless ``--reduced`` is given, which trains a small same-family config
+end-to-end with the identical code path minus the mesh.
 """
 from __future__ import annotations
 
@@ -22,9 +23,10 @@ from ..data import SyntheticLM
 from ..distributed.sharding import batch_shardings, params_shardings, opt_state_shardings
 from ..models import count_params, init_params
 from ..train import AdamWConfig, Trainer, TrainerConfig, adamw_init, make_train_step
+from .runtime import device_info, enable_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)
@@ -33,16 +35,19 @@ def main():
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--reduced", action="store_true",
-                    help="CPU-scale reduced config (default off-TPU)")
+                    help="train a small same-family config instead of the "
+                    "published width")
     ap.add_argument("--ckpt-dir", default="checkpoints")
     ap.add_argument("--mesh", default=None,
                     help="data,model e.g. 16,16 (default: single device)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    print("[train] devices: {platform} {kind} x{count}".format(**device_info()))
     cfg = get_config(args.arch)
-    if args.reduced or jax.default_backend() == "cpu":
+    if args.reduced:
         cfg = reduced_config(cfg)
-        print(f"[train] reduced config for {args.arch} on {jax.default_backend()}")
+        print(f"[train] reduced config for {args.arch}")
 
     params = init_params(cfg, seed=0)
     print(f"[train] params: {count_params(params):,}")

@@ -167,3 +167,28 @@ def test_deep_chain_single_kernel(rng):
     c = run(f, [("x", (8, 8), jnp.float32)], rng)
     assert c.stats.stitched_kernels == 1
     assert c.stats.standalone_kernels == 0
+
+
+def test_interpret_resolves_from_the_backend():
+    from repro.core import StitchOptions, compile_module
+    from repro.core.codegen import resolve_interpret
+
+    assert resolve_interpret(None) is True       # the CPU has no TPU
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
+    m = trace(lambda b, x: b.exp(x), ("x", (8, 128), jnp.float32))
+    assert StitchOptions().interpret is None
+    assert compile_module(m, StitchOptions()).stats.interpret is True
+
+
+def test_rank0_operands_and_results_cross_the_kernel_as_blocks(rng):
+    def f(b, x, s):
+        scaled = b.exp(x) * b.broadcast(s, (8, 128), ())
+        return b.reduce(scaled, (0, 1), "sum")
+
+    m = trace(f, ("x", (8, 128), jnp.float32), ("s", (), jnp.float32))
+    assert m.roots[0].shape == ()
+    compile_and_compare(
+        m, {"x": rng.randn(8, 128).astype("f4"), "s": np.float32(0.5)},
+        rtol=1e-5, atol=1e-4,
+    )

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import GraphBuilder, MemoryInfeasible, Sched, plan_memory, resolve_schedules
-from repro.core.memory import ALLOC, INLINE, SHARE, dominance_tree, dominates
+from repro.core.memory import ALLOC, INLINE, SHARE, dominance_tree, dominates, vmem_bytes
 from repro.core.schedule import ROW
 
 
@@ -56,13 +56,15 @@ def test_expensive_feeding_dot_through_bitcast_allocated():
 
 def test_shrinking_order_cheap_multiuser_first():
     b = GraphBuilder()
-    x = b.parameter("x", (64, 64), jnp.float32)   # 16 KiB chunks
+    x = b.parameter("x", (64, 64), jnp.float32)   # 32 KiB once lane-padded
     cheap = x + x                                  # cheap multi-user
     e = b.exp(x)                                   # expensive multi-user
     _ = cheap * e + (cheap - e)
     members, roots, sol = _resolve(b, None)
-    # budget fits only one buffer: the cheap one is dropped first
-    plan = plan_memory(members, roots, sol, vmem_limit=20 * 1024)
+    # budget fits the double-buffered I/O blocks plus only one buffer: the
+    # cheap one is dropped first
+    io = plan_memory(members, roots, sol).io_bytes
+    plan = plan_memory(members, roots, sol, vmem_limit=io + 40 * 1024)
     assert plan.action(cheap.instr) == INLINE
     assert plan.action(e.instr) == ALLOC
     assert plan.num_shrinks == 1
@@ -128,3 +130,29 @@ def test_no_sharing_between_concurrently_live_buffers():
         if plan.entries[i.instr.id].action in (ALLOC, SHARE)
     }
     assert len(slots) == 2, "live buffers must not share a slot"
+
+
+@pytest.mark.parametrize("shape,dtype,nbytes", [
+    ((8, 128), jnp.float32, 4096),
+    ((50, 40), jnp.float32, 56 * 128 * 4),     # padded to (56, 128)
+    ((16, 128), jnp.bfloat16, 4096),
+    ((8, 128), jnp.bfloat16, 16 * 128 * 2),    # 16-bit tiles hold 16 rows
+    ((40,), jnp.float32, 8 * 128 * 4),         # rank 1 lays out as one row
+    ((), jnp.float32, 8 * 128 * 4),            # rank 0 travels as (1, 1)
+    ((3, 8, 128), jnp.float32, 3 * 4096),
+])
+def test_vmem_bytes_pads_to_the_tiling(shape, dtype, nbytes):
+    assert vmem_bytes(shape, dtype) == nbytes
+
+
+def test_io_blocks_are_double_buffered_in_the_plan():
+    b = GraphBuilder()
+    x = b.parameter("x", (64, 128), jnp.float32)
+    y = b.exp(x) + x
+    members, roots, sol = _resolve(b, y, sword=4)   # (16, 128) blocks
+    plan = plan_memory(members, roots, sol)
+    # one input and one output block, 8 KiB each, two buffers apiece
+    assert plan.io_bytes == 2 * 2 * 16 * 128 * 4
+    assert plan.vmem_need >= plan.io_bytes
+    with pytest.raises(MemoryInfeasible):
+        plan_memory(members, roots, sol, vmem_limit=plan.io_bytes - 1)

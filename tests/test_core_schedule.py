@@ -17,7 +17,7 @@ from repro.core import (
     propagate,
     resolve_schedules,
 )
-from repro.core.schedule import ROW, COLUMN, block_index
+from repro.core.schedule import ROW, COLUMN, block_index, reshape_legal, tile_legal
 
 
 # ---------------------------------------------------------------- blocks math
@@ -183,3 +183,71 @@ def test_resolution_rejects_oversized_replication():
             members, [y.instr], {y.instr.id: Sched("chunked", 0, 512, ROW)},
             replicate_limit=64 * 1024,
         )
+
+
+# ------------------------------------------------------------- TPU tiling
+@pytest.mark.parametrize("shape,chunk,dtype,legal", [
+    ((400, 40), (400, 40), np.float32, True),      # whole array
+    ((400, 40), (50, 40), np.float32, False),      # 50 rows: not 8-aligned
+    ((400, 40), (80, 40), np.float32, True),
+    ((512, 1024), (512, 128), np.float32, True),
+    ((512, 1024), (512, 64), np.float32, False),   # 64 lanes of 1024
+    ((32, 128), (8, 128), np.float32, True),
+    ((32, 128), (8, 128), jnp.bfloat16, False),    # 16-bit: 16 sublanes
+    ((32, 128), (16, 128), jnp.bfloat16, True),
+    ((1024,), (64,), np.float32, False),           # rank 1: 128-multiple
+    ((1024,), (256,), np.float32, True),
+    ((4, 8, 128), (1, 8, 128), np.float32, True),  # leading dims are free
+])
+def test_tile_legal(shape, chunk, dtype, legal):
+    assert tile_legal(shape, chunk, dtype) is legal
+
+
+@pytest.mark.parametrize("shape", [(400, 40), (1024,), (4, 16, 512, 64), (64, 100)])
+def test_candidate_schedules_are_tile_legal(shape):
+    cands = candidate_schedules(shape)
+    assert cands
+    assert all(tile_legal(shape, chunk_shape(shape, c)) for c in cands)
+
+
+@pytest.mark.parametrize("src,dst,legal", [
+    ((16, 128), (2048,), False),          # lanes change
+    ((512, 1024), (512, 16, 64), False),
+    ((1, 16, 512, 64), (16, 512, 64), True),   # leading dims only
+    ((8, 128), (1, 8, 128), True),
+    ((16, 128), (2, 8, 128), True),       # whole 8-row tiles both sides
+])
+def test_reshape_legal(src, dst, legal):
+    assert reshape_legal(src, dst) is legal
+
+
+def _lane_changing_reshape():
+    b = GraphBuilder()
+    x = b.parameter("x", (16, 128), jnp.float32)
+    y = b.reshape(b.exp(x), (2048,))
+    members = [i for i in b.module.instructions if i.opcode != "parameter"]
+    return members, y.instr
+
+
+def test_untileable_reshape_root_exits_the_kernel():
+    members, root = _lane_changing_reshape()
+    sol = resolve_schedules(members, [root], {root.id: REPLICATED})
+    assert root.id in sol.exits
+    # the kernel writes the reshape's operand; the reshape runs outside
+    shape, sched = sol.block(root)
+    assert shape == (16, 128) and sched.kind == "replicated"
+
+
+def test_untileable_reshape_without_exits_is_unsatisfiable():
+    members, root = _lane_changing_reshape()
+    with pytest.raises(Unsatisfiable, match="in-kernel reshape"):
+        resolve_schedules(members, [root], {root.id: REPLICATED}, allow_exits=False)
+
+
+def test_resolution_rejects_untileable_block():
+    b = GraphBuilder()
+    x = b.parameter("x", (400, 40), jnp.float32)
+    y = b.exp(x)
+    members = [y.instr]
+    with pytest.raises(Unsatisfiable, match="tiling"):
+        resolve_schedules(members, [y.instr], {y.instr.id: Sched("chunked", 0, 8, ROW)})

@@ -142,7 +142,7 @@ def test_param_spec_fallback_small_dim_to_fsdp():
     # back to fsdp only when the big dim could NOT take it.
     mesh = FakeMesh({"data": 4, "model": 16})
     s = param_spec("/x/w", (30, 24), mesh)   # 30 % 4 != 0 -> big dim open
-    assert s[1] == ("data",) and s[0] is None  # small dim takes the fsdp axes
+    assert s[1] == "data" and s[0] is None  # small dim takes the fsdp axes
 
 
 def test_param_layout_bridges_spec_to_stitch_layout():

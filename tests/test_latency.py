@@ -123,3 +123,26 @@ def test_fusion_time_charges_replication_duplication():
         assignment={k: REPLICATED for k in sol.assignment},
     )
     assert model.fusion_time(f.members, f.roots, repl_sol) > base
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_device_spec_by_device_kind():
+    from repro.core.latency import DEVICE_SPECS, device_spec
+
+    assert device_spec(_FakeDevice("tpu", "TPU v5 lite")) is TPU_V5E
+    assert DEVICE_SPECS["TPU v5 lite"] is TPU_V5E
+    # the CPU plans for the v5e by name
+    assert device_spec(_FakeDevice("cpu", "cpu")) is TPU_V5E
+    assert LatencyModel().spec is TPU_V5E
+
+
+def test_device_spec_unknown_kind_raises_naming_it():
+    from repro.core.latency import device_spec
+
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        device_spec(_FakeDevice("tpu", "TPU v9 imaginary"))

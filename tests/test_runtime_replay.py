@@ -298,3 +298,34 @@ def test_multi_root_builder_graph_parity(rng):
     assert len(eager) >= 2
     for k in eager:
         assert np.array_equal(np.asarray(eager[k]), np.asarray(traced[k]))
+
+
+@pytest.mark.parametrize("jit_replay", [True, False], ids=["traced", "eager"])
+def test_standalone_transpose_folds_into_library_dot(jit_replay, rng):
+    """dot(x^T, w) and dot(g, w^T) read x and w directly: no transpose step
+    runs, and the result is XLA's own transposed-operand dot, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    def f(b, x, w, g):
+        xw = b.dot(b.transpose(x, (1, 0)), w, fusable=False)      # (16, 4)
+        gw = b.dot(g, b.transpose(w, (1, 0)), fusable=False)      # (2, 8)
+        return xw, gw
+
+    m = trace(f, ("x", (8, 16), jnp.float32), ("w", (8, 4), jnp.float32),
+              ("g", (2, 4), jnp.float32))
+    comp = compile_module(m, StitchOptions(jit_replay=jit_replay))
+    ep = comp.executable.execution_plan
+    assert not any(
+        getattr(s, "instr", None) is not None and s.instr.opcode == "transpose"
+        for s in ep.steps
+    )
+    feeds = {k: rng.randn(*s).astype("f4")
+             for k, s in (("x", (8, 16)), ("w", (8, 4)), ("g", (2, 4)))}
+    out = comp(feeds)
+    want = jax.jit(lambda x, w, g: (
+        jax.lax.dot_general(x, w, (((0,), (0,)), ((), ()))),
+        jax.lax.dot_general(g, w, (((1,), (1,)), ((), ()))),
+    ))(feeds["x"], feeds["w"], feeds["g"])
+    for r, w_ in zip(m.roots, want, strict=True):
+        assert np.array_equal(np.asarray(out[r.name]), np.asarray(w_))
