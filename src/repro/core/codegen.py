@@ -15,6 +15,9 @@ Emits ONE ``pl.pallas_call`` per fused computation:
   * INLINE ops are evaluated as straight vector expressions — thread
     composition (XLA's elemental emitter analogue, Algorithm 2's fallback
     branch).
+  * the call is named ``stitch_<8 hex>`` from the fusion signature
+    (``kernel_name``), so the kernel keeps one name in HLO and in the
+    device trace, shared by every fusion instance it serves.
 
 The same ``apply_op`` interpreter evaluates ops here (on VMEM tiles) and in
 the reference executor (on full arrays), so kernels match the oracle by
@@ -48,6 +51,7 @@ from .schedule import (
     chunk_shape,
     propagate,
 )
+from .signature import fusion_signature
 
 #: VMEM Mosaic keeps for itself on top of the planned buffers.
 VMEM_HEADROOM = 4 * 1024 * 1024
@@ -250,6 +254,7 @@ class StitchedKernel:
     inputs: List[Instruction]
     outputs: List[Instruction]
     stitched: Optional[StitchedSolution] = None
+    name: str = ""                       # the ``pallas_call`` name
 
     @property
     def blocks(self) -> int:
@@ -274,8 +279,15 @@ class StitchedKernel:
         """
         return StitchedKernel(
             fusion, self.solution, self.plan, self.fn,
-            fusion.inputs, fusion.roots, stitched=self.stitched,
+            fusion.inputs, fusion.roots, stitched=self.stitched, name=self.name,
         )
+
+
+def kernel_name(fusion: FusedComputation) -> str:
+    """``stitch_<8 hex of the fusion signature>``: the name the kernel
+    carries into HLO and the device trace, shared by every instance of one
+    signature."""
+    return "stitch_" + fusion_signature(fusion)[:8]
 
 
 def emit_fusion(
@@ -363,6 +375,7 @@ def emit_fusion(
             if m.id in root_pos:
                 _store(out_refs[root_pos[m.id]], v)
 
+    name = kernel_name(fusion)
     call = pl.pallas_call(
         kernel,
         grid=(blocks,),
@@ -372,8 +385,10 @@ def emit_fusion(
         scratch_shapes=scratch_shapes,
         interpret=resolve_interpret(interpret),
         compiler_params=_compiler_params(plan.vmem_need),
+        name=name,
     )
-    return StitchedKernel(fusion, solution, plan, _boundary(call, inputs, roots), inputs, roots)
+    return StitchedKernel(fusion, solution, plan, _boundary(call, inputs, roots),
+                          inputs, roots, name=name)
 
 
 # --------------------------------------------------------------------------
@@ -518,6 +533,7 @@ def emit_stitched_fusion(
                     if m.id in root_pos:
                         _store_chunk(out_refs[root_pos[m.id]], m, sched, v, b)
 
+    name = kernel_name(fusion)
     call = pl.pallas_call(
         kernel,
         grid=(1,),
@@ -527,8 +543,9 @@ def emit_stitched_fusion(
         scratch_shapes=scratch_shapes,
         interpret=resolve_interpret(interpret),
         compiler_params=_compiler_params(plan.vmem_need),
+        name=name,
     )
     return StitchedKernel(
         fusion, None, plan, _boundary(call, inputs, roots), inputs, roots,
-        stitched=stitched,
+        stitched=stitched, name=name,
     )

@@ -9,12 +9,12 @@ returns a ``CompiledModule`` wrapping the planned executable and stats.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field, replace
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..tracing import span
 from .codegen import StitchedKernel, resolve_interpret
 from .executor import StitchedExecutable
 from .fusion import FusionPlan
@@ -537,35 +537,35 @@ def compile_module(
     that salts every cache key.
     """
     opts = resolve_options(options or StitchOptions())
-    t0 = time.perf_counter()
-    library = PerfLibrary(opts.perf_library_path)
-    store = measured_store
-    if store is None and (opts.autotune or opts.tuning_store_path):
-        from .measure import MeasuredCostStore, device_fingerprint
+    with span("repro.compile") as timed:
+        library = PerfLibrary(opts.perf_library_path)
+        store = measured_store
+        if store is None and (opts.autotune or opts.tuning_store_path):
+            from .measure import MeasuredCostStore, device_fingerprint
 
-        store = MeasuredCostStore(
-            opts.tuning_store_path,
-            device_fp=device_fingerprint(library.model.spec, opts.interpret),
+            store = MeasuredCostStore(
+                opts.tuning_store_path,
+                device_fp=device_fingerprint(library.model.spec, opts.interpret),
+            )
+        state = CompilationState(
+            module=module,
+            options=opts,
+            library=library,
+            kernel_cache=(
+                kernel_cache
+                if kernel_cache is not None
+                else KernelCache(opts.kernel_cache_path)
+            ),
+            measured_store=store,
+            measured_base_hits=store.hits if store else 0,
+            measured_base_misses=store.misses if store else 0,
+            donate_params=frozenset(donate_params) if donate_params else None,
+            mesh=mesh,
+            param_layouts=param_layouts,
+            out_layouts=out_layouts,
         )
-    state = CompilationState(
-        module=module,
-        options=opts,
-        library=library,
-        kernel_cache=(
-            kernel_cache
-            if kernel_cache is not None
-            else KernelCache(opts.kernel_cache_path)
-        ),
-        measured_store=store,
-        measured_base_hits=store.hits if store else 0,
-        measured_base_misses=store.misses if store else 0,
-        donate_params=frozenset(donate_params) if donate_params else None,
-        mesh=mesh,
-        param_layouts=param_layouts,
-        out_layouts=out_layouts,
-    )
-    default_pipeline().run(state)
-    state.stats.compile_time_s = time.perf_counter() - t0
+        default_pipeline().run(state)
+    state.stats.compile_time_s = timed.seconds
     state.stats.pass_times = dict(state.pass_times)
     if opts.perf_library_path:
         state.library.save()

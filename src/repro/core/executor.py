@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tracing import span
 from .codegen import StitchedKernel
 from .fusion import FusionPlan, constant_like
 from .ir import Instruction, Module, apply_op
@@ -124,15 +125,18 @@ def order_units(plan: FusionPlan) -> List[object]:
 
 
 class _KernelStep:
-    """One stitched-kernel launch, pre-bound to its buffer slots."""
+    """One stitched-kernel launch, pre-bound to its buffer slots.  ``name``
+    is the fusion instance's; the kernel's own name is shared by every
+    instance of its signature."""
 
-    __slots__ = ("kernel", "arg_slots", "out_slots", "release")
+    __slots__ = ("kernel", "arg_slots", "out_slots", "release", "name")
 
-    def __init__(self, kernel: StitchedKernel, arg_slots, out_slots):
+    def __init__(self, kernel: StitchedKernel, arg_slots, out_slots, name: str):
         self.kernel = kernel
         self.arg_slots = arg_slots
         self.out_slots = out_slots
         self.release: List[int] = []
+        self.name = name
 
 
 class _OpStep:
@@ -147,6 +151,10 @@ class _OpStep:
         self.out_slot = out_slot
         self.release: List[int] = []
         self.fn = fn or functools.partial(apply_op, instr)
+
+    @property
+    def name(self) -> str:
+        return self.instr.name
 
 
 def _is_transpose_2d(instr: Instruction) -> bool:
@@ -349,7 +357,9 @@ class _JitSegment:
         XLA program.  Step outputs pass through ``optimization_barrier`` so
         XLA cannot re-fuse across step boundaries — fusion decisions belong
         to the FusionStitching passes, and the barrier keeps the traced
-        program step-for-step equivalent to the eager oracle."""
+        program step-for-step equivalent to the eager oracle.  Each step
+        runs under ``jax.named_scope(step.name)``, which ties its device
+        operations to the plan in the trace's op metadata."""
         steps, in_slots, out_slots = self.steps, self.in_slots, self.out_slots
 
         def seg(*vals):
@@ -357,17 +367,24 @@ class _JitSegment:
             local: Dict[int, object] = dict(zip(in_slots, vals, strict=False))
             for step in steps:
                 args = [local[s] for s in step.arg_slots]
-                if type(step) is _KernelStep:
-                    outs = jax.lax.optimization_barrier(step.kernel(*args))
-                    for s, o in zip(step.out_slots, outs, strict=False):
-                        local[s] = o
-                else:
-                    local[step.out_slot] = jax.lax.optimization_barrier(
-                        step.fn(*args)
-                    )
+                with jax.named_scope(step.name):
+                    if type(step) is _KernelStep:
+                        outs = jax.lax.optimization_barrier(step.kernel(*args))
+                        for s, o in zip(step.out_slots, outs, strict=False):
+                            local[s] = o
+                    else:
+                        local[step.out_slot] = jax.lax.optimization_barrier(
+                            step.fn(*args)
+                        )
             return tuple(local[s] for s in out_slots)
 
         self.fn = jax.jit(seg, donate_argnums=self.donate)
+
+
+def _dispatch_span(first: bool) -> str:
+    """The span of one replay-segment call: the first call of a segment
+    traces and compiles it, later calls only enqueue it."""
+    return "repro.replay_build" if first else "repro.dispatch"
 
 
 class ExecutionPlan:
@@ -491,7 +508,7 @@ class ExecutionPlan:
                 k = kernels[u.name]
                 arg_slots = [slot_of[i.id] for i in k.inputs]
                 out_slots = [new_slot(r.id) for r in k.outputs]
-                self.steps.append(_KernelStep(k, arg_slots, out_slots))
+                self.steps.append(_KernelStep(k, arg_slots, out_slots, u.name))
 
         self.num_slots = len(slot_of)
         self._root_binds: List[Tuple[str, int]] = [
@@ -669,10 +686,11 @@ class ExecutionPlan:
         intermediate buffers are donated, so caller-held feed arrays (jax
         or numpy) stay valid across calls.
         """
-        vals = self._bind_feeds(feeds)
-        buf = list(self._template)
-        for (name, slot, dtype, shape), v in zip(self._param_binds, vals, strict=False):
-            buf[slot] = v
+        with span("repro.bind"):
+            vals = self._bind_feeds(feeds)
+            buf = list(self._template)
+            for (name, slot, dtype, shape), v in zip(self._param_binds, vals, strict=False):
+                buf[slot] = v
         with warnings.catch_warnings():
             # donation on backends without aliasing support (CPU) only warns
             warnings.filterwarnings(
@@ -680,17 +698,20 @@ class ExecutionPlan:
             )
             for seg in self._segments:
                 if type(seg) is _LoopStep:
-                    outs = seg.run_traced(
-                        [buf[s] for s in seg.arg_slots], self._count_trace
-                    )
+                    with span(_dispatch_span(seg._scan_fn is None)):
+                        outs = seg.run_traced(
+                            [buf[s] for s in seg.arg_slots], self._count_trace
+                        )
                     for s, o in zip(seg.out_slots, outs, strict=False):
                         buf[s] = o
                     for s in seg.release:
                         buf[s] = None
                     continue
-                if seg.fn is None:
+                first = seg.fn is None
+                if first:
                     seg.build(self._count_trace)
-                outs = seg.fn(*[buf[s] for s in seg.in_slots])
+                with span(_dispatch_span(first)):
+                    outs = seg.fn(*[buf[s] for s in seg.in_slots])
                 for s, o in zip(seg.out_slots, outs, strict=False):
                     buf[s] = o
                 for s in seg.released:
@@ -787,7 +808,8 @@ class StitchedExecutable:
                     f"(per-shard {tuple(shape)})"
                 )
             vals.append(v)
-        outs = self._sharded_fn(*vals)
+        with span(_dispatch_span(ep.stats.traced_calls == 0)):
+            outs = self._sharded_fn(*vals)
         ep.stats.traced_calls += 1
         return {name: o for (name, _), o in zip(ep._root_binds, outs, strict=False)}
 

@@ -20,10 +20,10 @@ drops are demoted to standalone kernels (never silently lost).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ..tracing import span
 from .codegen import StitchedKernel, emit_fusion, emit_stitched_fusion, resolve_interpret
 from .fusion import (
     FusedComputation,
@@ -138,9 +138,9 @@ class PassPipeline:
         boundaries = 0
         warnings = 0
         for p in self.passes:
-            t0 = time.perf_counter()
-            p.run(state)
-            state.pass_times[p.name] = time.perf_counter() - t0
+            with span(f"repro.pass.{p.name}") as timed:
+                p.run(state)
+            state.pass_times[p.name] = timed.seconds
             # "off" does zero verification work (no pass_times["verify"]
             # entry either — the no-overhead contract is testable);
             # "checkpoint" verifies the finished artifact once; "strict"
@@ -148,9 +148,9 @@ class PassPipeline:
             # introduced it.
             if mode == "off" or (mode == "checkpoint" and p is not self.passes[-1]):
                 continue
-            v0 = time.perf_counter()
-            diags = verify_state(state, pass_name=p.name)
-            verify_time += time.perf_counter() - v0
+            with span("repro.pass.verify") as timed:
+                diags = verify_state(state, pass_name=p.name)
+            verify_time += timed.seconds
             boundaries += 1
             errors = [d for d in diags if d.severity == ERROR]
             warnings += len(diags) - len(errors)

@@ -55,6 +55,7 @@ from ..core.compiler import (
 from ..core.ir import Module
 from ..core.shard import mesh_axes_of, wrap_shard_map
 from ..core.signature import KernelCache
+from ..tracing import span
 from .jaxpr_lower import (
     LoweredJaxpr,
     LoweredShardedJaxpr,
@@ -391,9 +392,10 @@ class StitchedFunction:
             inner = wrap_shard_map(
                 inner, self.mesh, self.in_specs, self.out_specs
             )
-        closed, out_shape = jax.make_jaxpr(inner, return_shape=True)(
-            *shaped_args, **shaped_kwargs
-        )
+        with span("repro.trace"):
+            closed, out_shape = jax.make_jaxpr(inner, return_shape=True)(
+                *shaped_args, **shaped_kwargs
+            )
         return closed, jax.tree_util.tree_structure(out_shape)
 
     def _get_measured_store(self):
@@ -411,13 +413,9 @@ class StitchedFunction:
         return self._measured_store
 
     def _lower(self, closed) -> LoweredJaxpr:
-        if self.mesh is not None:
-            return lower_sharded_jaxpr(
-                closed, name=self.name, fuse_dot=self.options.fuse_dot
-            )
-        return lower_jaxpr(
-            closed, name=self.name, fuse_dot=self.options.fuse_dot
-        )
+        lower = lower_sharded_jaxpr if self.mesh is not None else lower_jaxpr
+        with span("repro.lower"):
+            return lower(closed, name=self.name, fuse_dot=self.options.fuse_dot)
 
     def _compile_lowered(
         self, lowered: LoweredJaxpr, donate_params: Optional[frozenset]
@@ -479,20 +477,22 @@ class StitchedFunction:
 
     # -- the jit-shaped surface -------------------------------------------
     def __call__(self, *args, **kwargs):
-        key, leaves, static_pos, dyn_args, dyn_kwargs, n_args = (
-            self._signature(args, kwargs)
-        )
-        entry = self._plans.get(key)
-        if entry is None:
-            entry = self._compile(
-                key, args, kwargs, static_pos, dyn_args, dyn_kwargs, n_args
-            )
-        if entry.is_fallback:
-            return self._fallback()(*args, **kwargs)
-        feeds = dict(zip(entry.lowered.param_names, leaves, strict=False))
-        out = entry.compiled(feeds)
-        flat = [out[n] for n in entry.lowered.output_names]
-        return jax.tree_util.tree_unflatten(entry.out_tree, flat)
+        with span("repro.call"):
+            with span("repro.prepare"):
+                key, leaves, static_pos, dyn_args, dyn_kwargs, n_args = (
+                    self._signature(args, kwargs)
+                )
+                entry = self._plans.get(key)
+            if entry is None:
+                entry = self._compile(
+                    key, args, kwargs, static_pos, dyn_args, dyn_kwargs, n_args
+                )
+            if entry.is_fallback:
+                return self._fallback()(*args, **kwargs)
+            feeds = dict(zip(entry.lowered.param_names, leaves, strict=False))
+            out = entry.compiled(feeds)
+            flat = [out[n] for n in entry.lowered.output_names]
+            return jax.tree_util.tree_unflatten(entry.out_tree, flat)
 
     def lower(self, *args, **kwargs) -> Lowered:
         """A ``Lowered`` introspection handle (``jax.jit(...).lower()``
