@@ -17,7 +17,13 @@ Emits ONE ``pl.pallas_call`` per fused computation:
     branch).
   * the call is named ``stitch_<8 hex>`` from the fusion signature
     (``kernel_name``), so the kernel keeps one name in HLO and in the
-    device trace, shared by every fusion instance it serves.
+    device trace, shared by every fusion instance it serves;
+  * a parameter whose device lays it out with its two minor dims swapped
+    (stamped ``native_layout`` by ``stamp_native_layouts``) is read in that
+    layout: it crosses the call as ``swapaxes`` of itself, which XLA folds
+    into a bitcast, so no relayout copy runs before the kernel.  Inside,
+    a transpose that swaps it back and a dot that takes it as rhs use the
+    swapped block as it is; any other consumer swaps it in VMEM.
 
 The same ``apply_op`` interpreter evaluates ops here (on VMEM tiles) and in
 the reference executor (on full arrays), so kernels match the oracle by
@@ -30,13 +36,13 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
+from jax.experimental.layout import Layout
 from jax.experimental.pallas import tpu as pltpu
 
 from .fusion import FusedComputation
@@ -50,6 +56,7 @@ from .schedule import (
     block_index,
     chunk_shape,
     propagate,
+    tile_legal,
 )
 from .signature import fusion_signature
 
@@ -63,6 +70,60 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
+
+
+def default_minor_to_major(dtype, shape, device) -> tuple:
+    """Minor-to-major order of ``device``'s default layout for a
+    ``(dtype, shape)`` array: the layout a jitted call's arguments carry."""
+    pjrt = device.client.get_default_layout(np.dtype(dtype), tuple(shape), device)
+    return tuple(reversed(Layout.from_pjrt_layout(pjrt).major_to_minor))
+
+
+def minor_dims_swapped(dtype, shape, device) -> bool:
+    """Whether ``device`` lays a 32-bit ``(dtype, shape)`` array out
+    row-major except for its two minor dims, which are swapped: then
+    ``swapaxes(x, -1, -2)`` in row-major order is the same bytes.  A v5e
+    does this to ``f32[..., 1024, 64]``, keeping the long dim on the lanes;
+    a CPU never does."""
+    n = len(shape)
+    if n < 2 or np.dtype(dtype).itemsize != 4:
+        return False
+    swapped = (n - 2, n - 1) + tuple(range(n - 3, -1, -1))
+    return default_minor_to_major(dtype, shape, device) == swapped
+
+
+def stamp_native_layouts(module, device) -> None:
+    """Stamp ``attrs["native_layout"]`` on each parameter of ``module`` that
+    ``device`` lays out with its minor dims swapped, and clear it from the
+    rest.  In a jitted replay a parameter is a segment argument and carries
+    that default layout, so a kernel that reads it row-major makes XLA copy
+    it first; a stamped one is read swapped instead.  ``device`` None (a
+    loop body, whose parameters XLA lays out inside the loop) stamps
+    nothing.  The stamp salts ``fusion_signature``."""
+    for p in module.parameters:
+        if device is not None and minor_dims_swapped(p.dtype, p.shape, device):
+            p.attrs["native_layout"] = True
+        else:
+            p.attrs.pop("native_layout", None)
+
+
+def _swap(t) -> tuple:
+    """``t`` with its last two entries exchanged."""
+    t = tuple(t)
+    return t[:-2] + (t[-1], t[-2])
+
+
+def _native(instr: Instruction, chunk, windowed: bool = False) -> bool:
+    """Whether the kernel reads input ``instr`` in its native layout: a
+    stamped parameter whose swapped block Mosaic can tile.  Not when
+    ``windowed`` (held whole across a grid of blocks): a block's window of
+    it would then start at a traced offset on its lane dim, which Mosaic
+    takes only at multiples of 128."""
+    return (
+        bool(instr.attrs.get("native_layout"))
+        and not windowed
+        and tile_legal(_swap(instr.shape), _swap(chunk), instr.dtype)
+    )
 
 
 def _compiler_params(vmem_need: int):
@@ -92,18 +153,32 @@ def _store(ref, v) -> None:
 
 class _Input:
     """A kernel input: its ref, loaded whole on first use.  A block that
-    needs only a window of it reads the window from the ref instead."""
+    needs only a window of it reads the window from the ref instead.
+    ``swapped``: the ref holds the block with its two minor dims swapped
+    (``raw``); ``value`` is then swapped back in VMEM."""
 
-    def __init__(self, ref, shape):
+    def __init__(self, ref, shape, swapped: bool = False):
         self.ref = ref
         self.shape = tuple(shape)
+        self.swapped = swapped
+        self._raw = None
         self._val = None
+
+    @property
+    def raw(self):
+        if self._raw is None:
+            self._raw = _load(self.ref, self.shape)
+        return self._raw
 
     @property
     def value(self):
         if self._val is None:
-            self._val = _load(self.ref, self.shape)
+            self._val = jnp.swapaxes(self.raw, -1, -2) if self.swapped else self.raw
         return self._val
+
+
+def _swapped_input(v) -> bool:
+    return isinstance(v, _Input) and v.swapped
 
 
 def _value(v):
@@ -129,10 +204,10 @@ def _window(v, starts, sizes):
         if tuple(sizes) == tuple(val.shape):
             return val
         return val[tuple(slice(st, st + n) for st, n in zip(starts, sizes, strict=False))]
-    if not isinstance(v, _Input):
+    if not isinstance(v, _Input) or v.swapped:
         raise ValueError(
-            "a block-dependent window of an in-kernel value has no Mosaic "
-            "lowering; the schedule must deliver it as a block"
+            "a block-dependent window of an in-kernel value or of a swapped "
+            "input has no Mosaic lowering; the schedule must deliver it as a block"
         )
     return v.ref[tuple(pl.ds(st, n) for st, n in zip(starts, sizes, strict=False))]
 
@@ -152,11 +227,12 @@ def _adapt(val, opnd: Instruction, stored: Sched, needed: Sched, b):
     )
 
 
-def _dot(instr: Instruction, lhs, rhs):
+def _dot(instr: Instruction, lhs, rhs, rhs_swapped: bool = False):
     """A batched ``dot`` as Mosaic's matmul takes it: 2-D when the block
     holds one batch, else with the batch dims folded into one.  f32 dots
     contract at full f32 precision, as the oracle does (Mosaic's default
-    may round operands to bf16)."""
+    may round operands to bf16).  ``rhs_swapped``: ``rhs`` comes as
+    ``(..., N, K)`` and contracts on its last dim."""
     batch = tuple(lhs.shape[:-2])
     nb = int(np.prod(batch, dtype=np.int64))
     f32 = np.dtype(instr.dtype) == np.float32
@@ -164,15 +240,16 @@ def _dot(instr: Instruction, lhs, rhs):
         preferred_element_type=jnp.float32 if f32 else None,
         precision=jax.lax.Precision.HIGHEST if f32 else None,
     )
+    rc = 1 if rhs_swapped else 0       # the rhs matrix's contracting dim
     if nb == 1:
         out = jax.lax.dot_general(
             lhs.reshape(lhs.shape[-2:]), rhs.reshape(rhs.shape[-2:]),
-            (((1,), (0,)), ((), ())), **kw,
+            (((1,), (rc,)), ((), ())), **kw,
         )
     else:
         out = jax.lax.dot_general(
             lhs.reshape((nb,) + lhs.shape[-2:]), rhs.reshape((nb,) + rhs.shape[-2:]),
-            (((2,), (1,)), ((0,), (0,))), **kw,
+            (((2,), (rc + 1,)), ((0,), (0,))), **kw,
         )
     return out.reshape(batch + out.shape[-2:]).astype(instr.dtype)
 
@@ -217,6 +294,15 @@ def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
             v = _window(v, starts, sizes)
         return jax.lax.broadcast_in_dim(_value(v), out_chunk, dims)
 
+    # a swapped input taken as it is: the transpose that swaps it back is
+    # the block the ref holds, and a dot contracts on its last dim
+    if op == "transpose" and _swapped_input(ovals[0]) and (
+        tuple(a["perm"]) == _swap(range(len(instr.shape)))
+    ):
+        return ovals[0].raw
+    if op == "dot" and _swapped_input(ovals[1]):
+        return _dot(instr, _value(ovals[0]), ovals[1].raw, rhs_swapped=True)
+
     ovals = [_value(v) for v in ovals]
     if op in ("reshape", "bitcast"):
         return jnp.reshape(ovals[0], out_chunk)
@@ -255,6 +341,7 @@ class StitchedKernel:
     outputs: List[Instruction]
     stitched: Optional[StitchedSolution] = None
     name: str = ""                       # the ``pallas_call`` name
+    native: Tuple[bool, ...] = ()        # per input: read in its native layout
 
     @property
     def blocks(self) -> int:
@@ -280,6 +367,7 @@ class StitchedKernel:
         return StitchedKernel(
             fusion, self.solution, self.plan, self.fn,
             fusion.inputs, fusion.roots, stitched=self.stitched, name=self.name,
+            native=self.native,
         )
 
 
@@ -312,14 +400,26 @@ def emit_fusion(
                 "a kernel; it must stay a standalone schedule break"
             )
 
-    def spec(shape, sched: Sched) -> pl.BlockSpec:
+    def spec(shape, sched: Sched, native: bool = False) -> pl.BlockSpec:
         if not shape:
             return _full_spec(shape)
+        if native:
+            return pl.BlockSpec(
+                _swap(chunk_shape(shape, sched)),
+                functools.partial(_swapped_block_index, shape, sched),
+            )
         return pl.BlockSpec(
             chunk_shape(shape, sched), functools.partial(block_index, shape, sched)
         )
 
-    in_specs = [spec(tuple(i.shape), assign.get(i.id, REPLICATED)) for i in inputs]
+    in_scheds = [assign.get(i.id, REPLICATED) for i in inputs]
+    native = tuple(
+        _native(i, chunk_shape(i.shape, s), blocks > 1 and s.kind == "replicated")
+        for i, s in zip(inputs, in_scheds, strict=True)
+    )
+    in_specs = [
+        spec(tuple(i.shape), s, n) for i, s, n in zip(inputs, in_scheds, native, strict=True)
+    ]
     # an exit reshape's block is its operand's; _boundary reshapes it after
     out_blocks = [solution.block(r) for r in roots]
     out_specs = [spec(*blk) for blk in out_blocks]
@@ -345,9 +445,9 @@ def emit_fusion(
         vals: Dict[int, object] = {}
         for i, instr in enumerate(inputs):
             vals[instr.id] = _Input(
-                in_refs[i], chunk_shape(instr.shape, assign.get(instr.id, REPLICATED))
+                in_refs[i], chunk_shape(instr.shape, in_scheds[i]), native[i]
             )
-            stored[instr.id] = assign.get(instr.id, REPLICATED)
+            stored[instr.id] = in_scheds[i]
 
         for m in members:
             sched = assign[m.id]
@@ -387,8 +487,8 @@ def emit_fusion(
         compiler_params=_compiler_params(plan.vmem_need),
         name=name,
     )
-    return StitchedKernel(fusion, solution, plan, _boundary(call, inputs, roots),
-                          inputs, roots, name=name)
+    return StitchedKernel(fusion, solution, plan, _boundary(call, inputs, roots, native),
+                          inputs, roots, name=name, native=native)
 
 
 # --------------------------------------------------------------------------
@@ -402,14 +502,28 @@ def _full_spec(shape) -> pl.BlockSpec:
     return pl.BlockSpec(shape, lambda b, _n=len(shape): (0,) * _n)
 
 
-def _boundary(call, inputs: List[Instruction], roots: List[Instruction]) -> Callable:
-    """Wrap a pallas_call so rank-0 operands cross it as (1, 1) blocks and
-    exit reshapes apply to its outputs."""
+def _swapped_block_index(shape, sched: Sched, b) -> tuple:
+    return _swap(block_index(shape, sched, b))
+
+
+def _crossing(a, instr: Instruction, native: bool):
+    """An operand as it crosses the ``pallas_call``: rank 0 as a (1, 1)
+    block, a native-layout input with its minor dims swapped (a bitcast of
+    the parameter XLA hands in)."""
+    if not instr.shape:
+        return jnp.reshape(a, (1, 1))
+    return jnp.swapaxes(a, -1, -2) if native else a
+
+
+def _boundary(
+    call, inputs: List[Instruction], roots: List[Instruction], native: Tuple[bool, ...]
+) -> Callable:
+    """Wrap a pallas_call so its operands cross it as ``_crossing`` gives
+    them and exit reshapes apply to its outputs."""
 
     def fn(*args):
         args = [
-            jnp.reshape(a, (1, 1)) if not i.shape else a
-            for a, i in zip(args, inputs, strict=False)
+            _crossing(a, i, n) for a, i, n in zip(args, inputs, native, strict=True)
         ]
         outs = call(*args)
         outs = outs if isinstance(outs, (list, tuple)) else (outs,)
@@ -456,7 +570,11 @@ def emit_stitched_fusion(
     inputs = fusion.inputs
     roots = fusion.roots
 
-    in_specs = [_full_spec(i.shape) for i in inputs]
+    native = tuple(_native(i, i.shape) for i in inputs)
+    in_specs = [
+        _full_spec(_swap(i.shape) if n else i.shape)
+        for i, n in zip(inputs, native, strict=True)
+    ]
     out_specs = [_full_spec(r.shape) for r in roots]
     out_shape = [jax.ShapeDtypeStruct(_lift(r.shape), r.dtype) for r in roots]
 
@@ -483,7 +601,7 @@ def emit_stitched_fusion(
 
         global_vals: Dict[int, object] = {}
         for i, instr in enumerate(inputs):
-            global_vals[instr.id] = _Input(in_refs[i], instr.shape)
+            global_vals[instr.id] = _Input(in_refs[i], instr.shape, native[i])
 
         for pk, phase in enumerate(stitched.phases):
             assign = phase.solution.assignment
@@ -546,6 +664,6 @@ def emit_stitched_fusion(
         name=name,
     )
     return StitchedKernel(
-        fusion, None, plan, _boundary(call, inputs, roots), inputs, roots,
-        stitched=stitched, name=name,
+        fusion, None, plan, _boundary(call, inputs, roots, native), inputs, roots,
+        stitched=stitched, name=name, native=native,
     )

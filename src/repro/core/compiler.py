@@ -15,7 +15,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..tracing import span
-from .codegen import StitchedKernel, resolve_interpret
+from .codegen import StitchedKernel, resolve_interpret, stamp_native_layouts
 from .executor import StitchedExecutable
 from .fusion import FusionPlan
 from .memory import SCOPED_VMEM_BYTES
@@ -237,6 +237,10 @@ class CompileStats:
     verify_time_s: float = 0.0
     # whether the kernels run in the Pallas interpreter (resolved option)
     interpret: bool = True
+    # kernel-instance operands bound in their native layout: parameters the
+    # plan's device lays out with their minor dims swapped, read so by the
+    # kernel instead of after an XLA relayout copy (0 on a CPU)
+    native_layout_operands: int = 0
 
     @property
     def replay_dispatch_reduction(self) -> int:
@@ -497,6 +501,7 @@ def build_outputs(state: CompilationState) -> None:
         collective_breaks_spanned=breaks_spanned,
         sharded_instrs=state.shard_stats.get("sharded_instrs", 0),
         interpret=state.options.interpret,
+        native_layout_operands=sum(sum(p.kernel.native) for p in state.planned),
     )
 
 
@@ -515,6 +520,7 @@ def compile_module(
     mesh=None,
     param_layouts=None,
     out_layouts=None,
+    device=None,
 ) -> CompiledModule:
     """Compile a StitchIR module through the default pass pipeline.
 
@@ -535,9 +541,14 @@ def compile_module(
     parameter names / outputs to ``core.shard`` layout tuples.  The mesh's
     (name, size) shape must match ``options.mesh_axes`` — the hashable half
     that salts every cache key.
+
+    ``device`` is the device the plan runs on: kernels read each parameter
+    in that device's default layout for it (``codegen.stamp_native_layouts``).
+    None reads every parameter row-major, as a loop body must.
     """
     opts = resolve_options(options or StitchOptions())
     with span("repro.compile") as timed:
+        stamp_native_layouts(module, device)
         library = PerfLibrary(opts.perf_library_path)
         store = measured_store
         if store is None and (opts.autotune or opts.tuning_store_path):

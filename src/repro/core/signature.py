@@ -56,8 +56,9 @@ def _canon_attrs(attrs: Dict) -> Tuple:
 def fusion_signature(fusion: FusedComputation) -> str:
     """Content hash of a fusion's structure, independent of input bindings.
 
-    Covers: per-input (shape, dtype); per-member (opcode, shape, dtype,
-    canonical attrs, operand references as member/input ordinals, root-ness);
+    Covers: per-input (shape, dtype, and the shard and native-layout stamps
+    when set); per-member (opcode, shape, dtype, canonical attrs, operand
+    references as member/input ordinals, root-ness);
     and the planner's committed phase structure (``stitch_phases``) — a
     multi-phase stitched lowering and a single-schedule lowering of the same
     member graph must never alias in the kernel cache.
@@ -72,13 +73,16 @@ def fusion_signature(fusion: FusedComputation) -> str:
     # Input features carry the shard layout when one is stamped: per-shard
     # member shapes are already local, but a fusion fed by a model-sharded
     # parameter and one fed by a replicated parameter of the same local shape
-    # must never alias in the cache.  The entry is appended only when
-    # non-trivial so unsharded signatures stay byte-identical across versions.
+    # must never alias in the cache.  Likewise the native-layout stamp: a
+    # kernel that reads an input with its minor dims swapped is another
+    # kernel.  Each entry is appended only when set, so every other
+    # signature stays byte-identical across versions.
     feats: List = [
         ("phases", tuple(fusion.stitch_phases) if fusion.stitch_phases else None),
         tuple(
             (tuple(i.shape), str(np.dtype(i.dtype)))
             + ((("shard", _canon_value(i.attrs["shard"])),) if i.attrs.get("shard") else ())
+            + ((("native_layout", True),) if i.attrs.get("native_layout") else ())
             for i in inputs
         ),
     ]

@@ -417,8 +417,20 @@ class StitchedFunction:
         with span("repro.lower"):
             return lower(closed, name=self.name, fuse_dot=self.options.fuse_dot)
 
+    def _plan_device(self, dyn_args, dyn_kwargs):
+        """The device the plan runs on: the mesh's first, else the one
+        device an argument is placed on (an array, or a ``ShapeDtypeStruct``
+        with a sharding), else JAX's default device."""
+        if self.mesh is not None:
+            return self.mesh.devices.flat[0]
+        for leaf in jax.tree_util.tree_leaves((dyn_args, dyn_kwargs)):
+            sharding = getattr(leaf, "sharding", None)
+            if sharding is not None and len(sharding.device_set) == 1:
+                return next(iter(sharding.device_set))
+        return jax.devices()[0]
+
     def _compile_lowered(
-        self, lowered: LoweredJaxpr, donate_params: Optional[frozenset]
+        self, lowered: LoweredJaxpr, donate_params: Optional[frozenset], device
     ) -> CompiledModule:
         sharded = isinstance(lowered, LoweredShardedJaxpr)
         return compile_module(
@@ -428,6 +440,7 @@ class StitchedFunction:
             mesh=lowered.mesh if sharded else None,
             param_layouts=lowered.param_layouts if sharded else None,
             out_layouts=lowered.out_layouts if sharded else None,
+            device=device,
         )
 
     def _fallback(self) -> Callable:
@@ -468,6 +481,7 @@ class StitchedFunction:
         compiled = self._compile_lowered(
             lowered,
             self._donated_param_names(n_args, static_pos, dyn_args),
+            self._plan_device(dyn_args, dyn_kwargs),
         )
         self.num_compiles += 1
         entry = _PlanEntry(lowered, compiled, out_tree)
@@ -519,8 +533,9 @@ class StitchedFunction:
             )
             lowered = self._lower(closed)
             donate = self._donated_param_names(n_args, static_pos, dyn_args)
+            device = self._plan_device(dyn_args, dyn_kwargs)
             return Lowered(
-                lowered, lambda: self._compile_lowered(lowered, donate)
+                lowered, lambda: self._compile_lowered(lowered, donate, device)
             )
         if self._last is None:
             raise ValueError(

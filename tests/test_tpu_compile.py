@@ -7,9 +7,14 @@ accepts — value ``dynamic_slice``, rank-0 blocks, blocks off the (8, 128)
 tiling, too much VMEM, batched matmuls Mosaic cannot lower.  The graphs are
 one regression each: ReduceTowers (rank-0 results), Speech (tiling), NMT
 (batched dots), StitchPipe (a multi-phase stitched kernel) and W2V (rank-1
-blocks and an in-kernel gather).
+blocks and an in-kernel gather).  One full-width layer of the decode
+benchmark's program checks that its K and V reach their kernels in the
+layout the chip gives them, with no relayout copy.
 """
+import json
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +25,11 @@ from jax.sharding import SingleDeviceSharding
 from graphs import ALL_GRAPHS, nmt_fn, softmax_transpose_fn, swiglu_fn
 from repro import StitchOptions, stitch
 from repro.core import compile_module
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from bench.programs import qwen_decode  # noqa: E402
 
 #: the chip smoke's stitch programs at its widths (f32)
 SMOKE_PROGRAMS = {
@@ -70,8 +80,10 @@ def _compile_kernels(compiled, sharding) -> int:
 @pytest.mark.parametrize("name", sorted(SMOKE_PROGRAMS))
 def test_smoke_program_kernels_compile_for_v5e(name, one_chip):
     fn, shapes = SMOKE_PROGRAMS[name]
+    # placed on the described chip, so the plan reads each parameter in the
+    # layout the chip gives it, as the smoke's plans do
     args = jax.tree_util.tree_map(
-        lambda s: jax.ShapeDtypeStruct(s, jnp.float32), shapes,
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip), shapes,
         is_leaf=lambda s: isinstance(s, tuple) and all(isinstance(d, int) for d in s),
     )
     compiled = stitch(fn, options=OPTS).lower(*args).compile()
@@ -82,3 +94,49 @@ def test_smoke_program_kernels_compile_for_v5e(name, one_chip):
 def test_graph_kernels_compile_for_v5e(name, one_chip):
     compiled = compile_module(ALL_GRAPHS[name](), OPTS)
     assert _compile_kernels(compiled, one_chip) >= 1
+
+
+def _entry(hlo: str) -> str:
+    start = hlo.index("\nENTRY")
+    return hlo[start: hlo.index("\n}", start)]
+
+
+def _param_copies(entry: str):
+    """``copy`` instructions of an HLO entry computation whose operand is
+    one of its parameters: XLA relaying a parameter out for a consumer."""
+    params = set(re.findall(r"%([\w.\-]+) = \S+ parameter\(\d+\)", entry))
+    return [
+        line.strip() for line in entry.splitlines()
+        if (m := re.search(r" copy\((?:[^%)]*)%([\w.\-]+)\)", line)) and m.group(1) in params
+    ]
+
+
+def test_decode_layer_reads_kv_in_the_v5e_layout(one_chip):
+    """One full-width layer of the decode program: K and V, f32[32, 16,
+    1024, 64] laid out {2,3,1,0} on a v5e, reach their kernels through a
+    bitcast, with no relayout copy in the replay segment."""
+    cfg = json.load(open(os.path.join(ROOT, "bench/configs/qwen1.5-0.5b-f32-5layers.json")))
+    cfg["num_hidden_layers"] = 1
+    traffic = json.load(open(os.path.join(ROOT, "bench/traffic/decode.json")))
+    shapes = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        qwen_decode.arg_shapes(cfg, traffic),
+    )
+    fn = qwen_decode.program(cfg, traffic)
+    compiled = stitch(fn, options=StitchOptions(interpret=False)).lower(*shapes).compile()
+    assert compiled.stats.native_layout_operands == 2
+    ep = compiled.executable.execution_plan
+    (seg,) = ep._segments
+    seg.build(lambda: None)
+    params = {slot: (shape, dtype) for _, slot, dtype, shape in ep._param_binds}
+    args = []
+    for s in seg.in_slots:
+        shape, dtype = params.get(s) or (ep._template[s].shape, ep._template[s].dtype)
+        args.append(jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype), sharding=one_chip))
+    entry = _entry(seg.fn.lower(*args).compile().as_text())
+    assert "tpu_custom_call" in entry
+    assert _param_copies(entry) == []
+    kv = re.findall(r"%([\w.\-]+) = f32\[32,16,1024,64\]\{2,3,1,0:T\(8,128\)\} parameter", entry)
+    assert len(kv) == 2
+    for name in kv:
+        assert re.search(rf"bitcast\(%{re.escape(name)}\)", entry)
