@@ -148,6 +148,7 @@ def _load(ref, shape):
 
 
 def _store(ref, v) -> None:
+    v = _value(v)
     ref[...] = v.reshape(ref.shape) if v.ndim == 0 else v
 
 
@@ -177,12 +178,35 @@ class _Input:
         return self._val
 
 
+class _MinorSwapped:
+    """A transpose of the two minor dims of ``raw``, not done yet: a dot
+    that takes it as rhs contracts ``raw`` on its last dim instead (the
+    MQA scores ``q @ k.T`` read K as it lies), and any other consumer swaps
+    it in VMEM."""
+
+    swapped = True
+
+    def __init__(self, raw):
+        self.raw = raw
+        self._val = None
+
+    @property
+    def shape(self) -> tuple:
+        return _swap(self.raw.shape)
+
+    @property
+    def value(self):
+        if self._val is None:
+            self._val = jnp.swapaxes(self.raw, -1, -2)
+        return self._val
+
+
 def _swapped_input(v) -> bool:
-    return isinstance(v, _Input) and v.swapped
+    return isinstance(v, (_Input, _MinorSwapped)) and v.swapped
 
 
 def _value(v):
-    return v.value if isinstance(v, _Input) else v
+    return v.value if isinstance(v, (_Input, _MinorSwapped)) else v
 
 
 def _starts(shape, sched: Sched, b):
@@ -295,11 +319,12 @@ def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
         return jax.lax.broadcast_in_dim(_value(v), out_chunk, dims)
 
     # a swapped input taken as it is: the transpose that swaps it back is
-    # the block the ref holds, and a dot contracts on its last dim
-    if op == "transpose" and _swapped_input(ovals[0]) and (
-        tuple(a["perm"]) == _swap(range(len(instr.shape)))
-    ):
-        return ovals[0].raw
+    # the block the ref holds, and a dot contracts on its last dim; any
+    # other swap of the minor dims is deferred to its consumers
+    if op == "transpose" and tuple(a["perm"]) == _swap(range(len(instr.shape))):
+        if _swapped_input(ovals[0]):
+            return ovals[0].raw
+        return _MinorSwapped(_value(ovals[0]))
     if op == "dot" and _swapped_input(ovals[1]):
         return _dot(instr, _value(ovals[0]), ovals[1].raw, rhs_swapped=True)
 
@@ -542,7 +567,7 @@ def _store_chunk(ref, instr: Instruction, sched: Sched, v, b: int):
         return
     starts = _starts(instr.shape, sched, b)
     cs = chunk_shape(instr.shape, sched)
-    ref[tuple(slice(s, s + c) for s, c in zip(starts, cs, strict=False))] = v
+    ref[tuple(slice(s, s + c) for s, c in zip(starts, cs, strict=False))] = _value(v)
 
 
 def emit_stitched_fusion(

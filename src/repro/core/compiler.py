@@ -219,11 +219,13 @@ class CompileStats:
     model_error_pct: Optional[float] = None
     # Shard-aware compilation accounting (zero on single-device compiles):
     # collective steps in the plan (ICI traffic — counted apart from kernels
-    # and library calls), their modeled wire time, how many of them sit
+    # and library calls), the bytes each chip hands them per call (their
+    # per-shard operands), their modeled wire time, how many of them sit
     # BETWEEN two stitched kernels (compute fused on both sides of the
     # break — the tentpole's acceptance metric), and how many instructions
     # carry a non-trivial shard layout.
     collective_calls: int = 0
+    collective_bytes: int = 0
     collective_time_s: float = 0.0
     collective_breaks_spanned: int = 0
     sharded_instrs: int = 0
@@ -351,6 +353,7 @@ def build_outputs(state: CompilationState) -> None:
     library_time = 0.0
     collective_time = 0.0
     collective_calls = 0
+    collective_bytes = 0
     mesh_sizes = dict(getattr(state.options, "mesh_axes", None) or ())
     for s in plan.standalone:
         if s.opcode == "get":
@@ -363,6 +366,7 @@ def build_outputs(state: CompilationState) -> None:
                 g *= mesh_sizes.get(a, 1)
             collective_time += lib.model.collective_op_time(s, g)
             collective_calls += 1
+            collective_bytes += s.operands[0].bytesize
             continue
         if s.opcode == "call":
             # a loop costs its body's predicted time per iteration
@@ -497,6 +501,7 @@ def build_outputs(state: CompilationState) -> None:
         measurements_taken=state.measurements_taken,
         model_error_pct=float(np.mean(errors)) if errors else None,
         collective_calls=collective_calls,
+        collective_bytes=collective_bytes,
         collective_time_s=collective_time,
         collective_breaks_spanned=breaks_spanned,
         sharded_instrs=state.shard_stats.get("sharded_instrs", 0),

@@ -796,18 +796,19 @@ class StitchedExecutable:
     def sharded_execute(self, feeds: Dict[str, object]) -> Dict[str, object]:
         """One dispatch of the whole multi-device plan on global feeds."""
         ep = self.execution_plan
-        vals = []
-        for name, slot, dtype, shape in ep._param_binds:
-            if name not in feeds:
-                raise KeyError(f"missing feed for parameter {name}")
-            v = jnp.asarray(feeds[name], dtype=dtype)
-            want = self._global_shape(name, shape)
-            if tuple(v.shape) != want:
-                raise ValueError(
-                    f"{name}: global feed shape {tuple(v.shape)} != {want} "
-                    f"(per-shard {tuple(shape)})"
-                )
-            vals.append(v)
+        with span("repro.bind"):
+            vals = []
+            for name, slot, dtype, shape in ep._param_binds:
+                if name not in feeds:
+                    raise KeyError(f"missing feed for parameter {name}")
+                v = jnp.asarray(feeds[name], dtype=dtype)
+                want = self._global_shape(name, shape)
+                if tuple(v.shape) != want:
+                    raise ValueError(
+                        f"{name}: global feed shape {tuple(v.shape)} != {want} "
+                        f"(per-shard {tuple(shape)})"
+                    )
+                vals.append(v)
         with span(_dispatch_span(ep.stats.traced_calls == 0)):
             outs = self._sharded_fn(*vals)
         ep.stats.traced_calls += 1

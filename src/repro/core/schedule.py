@@ -129,13 +129,15 @@ def tile_legal(shape: Tuple[int, ...], chunk: Tuple[int, ...], dtype=np.float32)
 
     The last two block dims must each equal the array's or be a multiple of
     the (sublane, lane) tile; a rank-1 block must be whole or a multiple of
-    128.  Leading dims are free.
+    one whole (sublane x lane) tile, 1024 values of 32 bits, since XLA lays
+    a long rank-1 array out in such tiles and Mosaic takes a block only in
+    that layout.  Leading dims are free.
     """
     shape, chunk = tuple(shape), tuple(chunk)
     if chunk == shape:
         return True
     if len(shape) == 1:
-        return chunk[0] % LANES == 0
+        return chunk[0] % (LANES * sublanes(dtype)) == 0
     lane_ok = chunk[-1] == shape[-1] or chunk[-1] % LANES == 0
     sub_ok = chunk[-2] == shape[-2] or chunk[-2] % sublanes(dtype) == 0
     return lane_ok and sub_ok
@@ -439,6 +441,7 @@ def resolve_schedules(
     boundary = list(roots) + [
         o for m in members for o in m.operands if o.id not in member_ids
     ]
+    matrices = _dot_matrix_inputs(members)
     for instr in boundary:
         shape, sched = sol.block(instr)
         if sched.kind == "replicated":
@@ -446,9 +449,38 @@ def resolve_schedules(
         cs = chunk_shape(shape, sched)
         if not tile_legal(shape, cs, instr.dtype):
             raise Unsatisfiable(f"{instr.name}: block {cs} breaks the tiling")
-        if int(np.prod(cs)) * np.dtype(instr.dtype).itemsize > replicate_limit:
+        limit = replicate_limit
+        if instr.id in matrices and all(c == 1 for c in cs[:-2]):
+            limit = max(limit, DOT_BLOCK_LIMIT)
+        if int(np.prod(cs)) * np.dtype(instr.dtype).itemsize > limit:
             raise Unsatisfiable(f"{instr.name}: block {cs} > limit")
     return sol
+
+
+#: Cap on a block that holds one batch element's whole matrix of a batched
+#: dot operand: a quarter of the chip's default scoped VMEM, so the block
+#: fits double-buffered beside the kernel's other blocks (the memory plan
+#: checks the total).  Such a block is the finest the dot's schedule allows,
+#: so below this cap it is taken whatever ``replicate_limit`` says.
+DOT_BLOCK_LIMIT = 4 * 1024 * 1024
+
+
+def _dot_matrix_inputs(members: List[Instruction]) -> set:
+    """Ids of the values that a batched ``dot`` among ``members`` reads as
+    an operand, directly or through a transpose of its two minor dims."""
+    out = set()
+    for m in members:
+        if m.opcode != "dot" or m.ndim < 3:
+            continue
+        for o in m.operands:
+            out.add(o.id)
+            if o.opcode == "transpose" and tuple(o.attrs["perm"]) == _minor_swap(o.ndim):
+                out.add(o.operands[0].id)
+    return out
+
+
+def _minor_swap(n: int) -> Tuple[int, ...]:
+    return tuple(range(n - 2)) + (n - 1, n - 2)
 
 
 def any_satisfiable(

@@ -185,6 +185,25 @@ def test_resolution_rejects_oversized_replication():
         )
 
 
+def test_dot_matrix_block_may_pass_replicate_limit():
+    """MQA scores: one row's whole K, (1, 8192, 128) f32 = 4 MiB, is the
+    finest block the batched dot allows, so it passes the 512 KiB
+    replicate limit; the same block of an elementwise op does not."""
+    b = GraphBuilder()
+    q = b.parameter("q", (64, 12, 128), jnp.float32)
+    k = b.parameter("k", (64, 8192, 128), jnp.float32)
+    s = b.dot(q, b.transpose(k, (0, 2, 1)), fusable=True)
+    members = [i for i in b.module.instructions if i.opcode != "parameter"]
+    sol = resolve_schedules(members, [s.instr], {s.instr.id: Sched("chunked", 0, 64, ROW)})
+    assert sol.blocks == 64
+
+    b = GraphBuilder()
+    x = b.parameter("x", (64, 8192, 128), jnp.float32)
+    y = b.exp(x)
+    with pytest.raises(Unsatisfiable, match="limit"):
+        resolve_schedules([y.instr], [y.instr], {y.instr.id: Sched("chunked", 0, 64, ROW)})
+
+
 # ------------------------------------------------------------- TPU tiling
 @pytest.mark.parametrize("shape,chunk,dtype,legal", [
     ((400, 40), (400, 40), np.float32, True),      # whole array
@@ -195,8 +214,9 @@ def test_resolution_rejects_oversized_replication():
     ((32, 128), (8, 128), np.float32, True),
     ((32, 128), (8, 128), jnp.bfloat16, False),    # 16-bit: 16 sublanes
     ((32, 128), (16, 128), jnp.bfloat16, True),
-    ((1024,), (64,), np.float32, False),           # rank 1: 128-multiple
-    ((1024,), (256,), np.float32, True),
+    ((1024,), (64,), np.float32, False),           # rank 1: whole tiles
+    ((1024,), (256,), np.float32, False),          # XLA tiles f32[1024] by 1024
+    ((2048,), (1024,), np.float32, True),
     ((4, 8, 128), (1, 8, 128), np.float32, True),  # leading dims are free
 ])
 def test_tile_legal(shape, chunk, dtype, legal):

@@ -154,7 +154,8 @@ def test_compiled_segment_names_every_kernel_and_op():
 
 def test_sharded_replay_spans(fresh):
     """The one multi-device dispatch of a sharded plan: its first call is
-    ``repro.replay_build``, later calls ``repro.dispatch``."""
+    ``repro.replay_build``, later calls ``repro.dispatch``; the global feeds
+    are checked and converted in ``repro.bind``."""
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
@@ -170,4 +171,5 @@ def test_sharded_replay_spans(fresh):
     assert "repro.dispatch" not in totals()
     reset()
     st(x)
-    assert set(totals()) == {"repro.call", "repro.prepare", "repro.dispatch"}
+    assert set(totals()) == {"repro.call", "repro.prepare", "repro.bind",
+                             "repro.dispatch"}
