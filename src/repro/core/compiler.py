@@ -16,7 +16,7 @@ import numpy as np
 
 from ..tracing import span
 from .codegen import StitchedKernel, resolve_interpret, stamp_native_layouts
-from .executor import StitchedExecutable
+from .executor import LaunchStats, StitchedExecutable
 from .fusion import FusionPlan
 from .memory import SCOPED_VMEM_BYTES
 from .perf_library import PerfLibrary
@@ -243,6 +243,18 @@ class CompileStats:
     # plan's device lays out with their minor dims swapped, read so by the
     # kernel instead of after an XLA relayout copy (0 on a CPU)
     native_layout_operands: int = 0
+    # the executable's live LaunchStats, which the per-call counters read
+    runtime: Optional[LaunchStats] = field(default=None, repr=False, compare=False)
+
+    @property
+    def feeds_passed(self) -> int:
+        """Feeds bound as given, over every call so far."""
+        return self.runtime.feeds_passed if self.runtime is not None else 0
+
+    @property
+    def feeds_converted(self) -> int:
+        """Feeds converted with ``jnp.asarray``, over every call so far."""
+        return self.runtime.feeds_converted if self.runtime is not None else 0
 
     @property
     def replay_dispatch_reduction(self) -> int:
@@ -507,6 +519,7 @@ def build_outputs(state: CompilationState) -> None:
         sharded_instrs=state.shard_stats.get("sharded_instrs", 0),
         interpret=state.options.interpret,
         native_layout_operands=sum(sum(p.kernel.native) for p in state.planned),
+        runtime=executable.execution_plan.stats,
     )
 
 

@@ -78,6 +78,10 @@ class LaunchStats:
     eager_dispatches_per_call: int = 0   # pre-bound steps the eager loop runs
     traced_dispatches_per_call: int = 0  # jitted replay segments
     donated_buffers: int = 0         # dead-after-segment inputs donated to XLA
+    # feeds bound over every call so far (``_bind``): handed on as given,
+    # or converted with ``jnp.asarray`` and their shape checked
+    feeds_passed: int = 0
+    feeds_converted: int = 0
 
     @property
     def total_non_library(self) -> int:
@@ -122,6 +126,34 @@ def order_units(plan: FusionPlan) -> List[object]:
     if len(order) != len(units):
         raise RuntimeError("cyclic fusion plan — fusion cycle check failed")
     return [units[ui] for ui in order]
+
+
+def _bind(feeds: Dict[str, object], binds, stats: LaunchStats,
+          label: str = "feed shape") -> List[object]:
+    """Validated values of ``binds`` (laid out as ``_param_binds``) in
+    order.  A ``jax.Array`` feed of the parameter's dtype and shape, not
+    weakly typed, is bound as it is: ``jnp.asarray`` would return it
+    unchanged.  Any other feed is converted and its shape checked."""
+    vals = []
+    converted = 0
+    for name, _, dtype, shape in binds:
+        if name not in feeds:
+            raise KeyError(f"missing feed for parameter {name}")
+        v = feeds[name]
+        if not (
+            isinstance(v, jax.Array)
+            and v.dtype == dtype
+            and v.shape == shape
+            and not v.weak_type
+        ):
+            v = jnp.asarray(v, dtype=dtype)
+            if tuple(v.shape) != shape:
+                raise ValueError(f"{name}: {label} {tuple(v.shape)} != {shape}")
+            converted += 1
+        vals.append(v)
+    stats.feeds_passed += len(vals) - converted
+    stats.feeds_converted += converted
+    return vals
 
 
 class _KernelStep:
@@ -313,9 +345,9 @@ class _JitSegment:
     to XLA.  Only *intermediate* slots (produced by an earlier segment,
     owned by the runtime, fresh every call) are donated: template
     (folded-constant) buffers are shared across calls, and parameter
-    buffers may still be held by the caller (``jnp.asarray`` is a no-copy
-    passthrough for device-resident feeds — donating those would delete
-    arrays the caller reuses on the next call).
+    buffers may still be held by the caller (a device-resident feed is
+    bound as given — donating it would delete an array the caller reuses
+    on the next call).
     """
 
     __slots__ = ("steps", "in_slots", "out_slots", "released", "donate", "fn")
@@ -636,16 +668,8 @@ class ExecutionPlan:
         return [buf[s] for _, s in self._root_binds]
 
     def _bind_feeds(self, feeds: Dict[str, object]) -> List[object]:
-        """Validated parameter values in ``_param_binds`` order."""
-        vals = []
-        for name, slot, dtype, shape in self._param_binds:
-            if name not in feeds:
-                raise KeyError(f"missing feed for parameter {name}")
-            v = jnp.asarray(feeds[name], dtype=dtype)
-            if tuple(v.shape) != shape:
-                raise ValueError(f"{name}: feed shape {v.shape} != {shape}")
-            vals.append(v)
-        return vals
+        """Validated parameter values in ``_param_binds`` order (``_bind``)."""
+        return _bind(feeds, self._param_binds, self.stats)
 
     def execute(self, feeds: Dict[str, object]) -> Dict[str, object]:
         """Eager replay: one Python-dispatched XLA call per step (the
@@ -766,6 +790,10 @@ class StitchedExecutable:
         from .shard import layout_to_pspec, wrap_shard_map
 
         ep = self.execution_plan
+        self._global_binds = [
+            (name, slot, dtype, self._global_shape(name, shape))
+            for name, slot, dtype, shape in ep._param_binds
+        ]
         in_specs = tuple(
             layout_to_pspec(self.param_layouts.get(name))
             for name, _, _, _ in ep._param_binds
@@ -797,18 +825,7 @@ class StitchedExecutable:
         """One dispatch of the whole multi-device plan on global feeds."""
         ep = self.execution_plan
         with span("repro.bind"):
-            vals = []
-            for name, slot, dtype, shape in ep._param_binds:
-                if name not in feeds:
-                    raise KeyError(f"missing feed for parameter {name}")
-                v = jnp.asarray(feeds[name], dtype=dtype)
-                want = self._global_shape(name, shape)
-                if tuple(v.shape) != want:
-                    raise ValueError(
-                        f"{name}: global feed shape {tuple(v.shape)} != {want} "
-                        f"(per-shard {tuple(shape)})"
-                    )
-                vals.append(v)
+            vals = _bind(feeds, self._global_binds, ep.stats, "global feed shape")
         with span(_dispatch_span(ep.stats.traced_calls == 0)):
             outs = self._sharded_fn(*vals)
         ep.stats.traced_calls += 1
@@ -835,6 +852,8 @@ class StitchedExecutable:
             1 if self.mesh is not None else rt.traced_dispatches_per_call
         )
         st.donated_buffers = rt.donated_buffers
+        st.feeds_passed = rt.feeds_passed
+        st.feeds_converted = rt.feeds_converted
         return st
 
     def execute_eager(self, feeds: Dict[str, object]) -> Dict[str, object]:

@@ -84,6 +84,14 @@ def _leaf_spec(leaf) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(np.shape(leaf), jnp.result_type(leaf))
 
 
+def _leaf_key(leaf) -> Tuple[Tuple[int, ...], np.dtype]:
+    """A leaf's part of the plan key: a ``jax.Array``'s own shape and dtype,
+    else the shape and dtype tracing gives the leaf (``_leaf_spec``)."""
+    if isinstance(leaf, jax.Array):
+        return leaf.shape, leaf.dtype
+    return tuple(np.shape(leaf)), jnp.result_type(leaf)
+
+
 def _int_tuple(v, label: str) -> Tuple[int, ...]:
     if v is None:
         return ()
@@ -352,13 +360,7 @@ class StitchedFunction:
     def _signature(self, args, kwargs):
         statics, static_pos, dyn_args, dyn_kwargs = self._split(args, kwargs)
         leaves, treedef = jax.tree_util.tree_flatten((dyn_args, dyn_kwargs))
-        key = (
-            statics,
-            treedef,
-            tuple(
-                (tuple(np.shape(leaf)), str(jnp.result_type(leaf))) for leaf in leaves
-            ),
-        )
+        key = (statics, treedef, tuple(map(_leaf_key, leaves)))
         return key, leaves, static_pos, dyn_args, dyn_kwargs, len(args)
 
     def _trace(self, args, static_pos, dyn_args, dyn_kwargs, kwargs):
