@@ -33,7 +33,7 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-sys.path[:0] = [os.path.join(ROOT, "src"), ROOT]
+sys.path[:0] = [os.path.join(ROOT, "src"), os.path.join(ROOT, "tests")]
 
 #: serve phase settings at full size
 SERVE = dict(
@@ -106,7 +106,7 @@ def stitch_programs(full: bool = True):
     widths, or at small widths for a CPU run."""
     import numpy as np
 
-    from benchmarks.graphs import nmt_fn, softmax_transpose_fn, swiglu_args, swiglu_fn
+    from graphs import nmt_fn, softmax_transpose_fn, swiglu_args, swiglu_fn
 
     rng = np.random.RandomState(0)
     tokens, d, f = (512, 1024, 2816) if full else (16, 128, 256)
@@ -186,7 +186,7 @@ def sharded_phase(devices, *, tokens=512, d_model=1024, d_ff=2816, layers=2,
     import numpy as np
     from jax.sharding import Mesh
 
-    from benchmarks.graphs import TP_FAMILIES, stacked_tp_fn, stacked_tp_specs
+    from graphs import TP_FAMILIES, stacked_tp_fn, stacked_tp_specs
     from repro import StitchOptions, stitch
     from repro.core.shard import wrap_shard_map
 

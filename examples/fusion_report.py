@@ -4,11 +4,13 @@ VMEM scratch, sharing) — the compiler's explain-mode.
 
     PYTHONPATH=src python examples/fusion_report.py
 """
+import os
 import sys
 
-sys.path.insert(0, ".")  # for benchmarks.*
+# the benchmark graph registry, tests/graphs.py
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tests"))
 
-from benchmarks.graphs import ALL_GRAPHS
+from graphs import ALL_GRAPHS
 from repro.core import StitchOptions, compile_module
 
 

@@ -30,7 +30,7 @@ from repro import (
     stitch,
 )
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tests"))
 from graphs import JNP_FAMILIES, nmt_args
 
 OPTS = StitchOptions(max_blocks=64)

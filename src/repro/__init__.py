@@ -1,6 +1,6 @@
 """repro — FusionStitching (Long et al., 2018) reproduced as a production
 JAX/Pallas TPU framework: stitching compiler core, stitched kernels, model
-zoo, distributed training/serving substrate, multi-pod launch tooling.
+zoo, distributed training/serving substrate, serve and train launchers.
 
 Public surface (one coherent top level):
 
@@ -18,12 +18,9 @@ Public surface (one coherent top level):
     (``admit`` / ``tick`` / ``run_until_done`` / ``stats``).
 
 Lower-level names (``GraphBuilder``, ``trace``, ``lower_jaxpr``,
-``reference_execute``, primitive tables) now live in ``repro.core`` and
-``repro.frontend``; importing them from ``repro`` still works but emits a
-one-time ``DeprecationWarning`` naming the new home.
+``reference_execute``, primitive tables) live in ``repro.core`` and
+``repro.frontend``.
 """
-import warnings as _warnings
-
 __version__ = "1.2.0"
 
 from .core import (  # noqa: F401
@@ -71,38 +68,3 @@ __all__ = [
     "PagedServeEngine",
     "Request",
 ]
-
-# ---------------------------------------------------------------------------
-# Deprecated re-exports: the pre-1.2 flat surface.  Each name resolves to its
-# current home and warns once per process; new code should import from there.
-# ---------------------------------------------------------------------------
-
-_DEPRECATED = {
-    "GraphBuilder": ("repro.core", "GraphBuilder"),
-    "trace": ("repro.core", "trace"),
-    "reference_execute": ("repro.core", "reference_execute"),
-    "lower_jaxpr": ("repro.frontend", "lower_jaxpr"),
-    "SUPPORTED_PRIMITIVES": ("repro.frontend", "SUPPORTED_PRIMITIVES"),
-}
-_warned: set = set()
-
-
-def __getattr__(name):
-    if name in _DEPRECATED:
-        mod_name, attr = _DEPRECATED[name]
-        if name not in _warned:
-            _warned.add(name)
-            _warnings.warn(
-                f"importing {name!r} from 'repro' is deprecated; use "
-                f"'from {mod_name} import {attr}' instead",
-                DeprecationWarning,
-                stacklevel=2,
-            )
-        import importlib
-
-        return getattr(importlib.import_module(mod_name), attr)
-    raise AttributeError(f"module 'repro' has no attribute {name!r}")
-
-
-def __dir__():
-    return sorted(set(__all__) | set(_DEPRECATED) | set(globals()))

@@ -27,15 +27,6 @@ def get_config(name: str) -> ModelConfig:
     return ARCHITECTURES[name]
 
 
-# Input-shape cells assigned to the LM family (seq_len, global_batch, kind)
-SHAPES = {
-    "train_4k": dict(seq_len=4096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524288, global_batch=1, kind="decode"),
-}
-
-
 def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
     """Small same-family config for CPU smoke tests."""
     import dataclasses

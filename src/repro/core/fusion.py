@@ -161,7 +161,7 @@ class FusedComputation:
 
 @dataclass
 class PlannerStats:
-    """What the cost-guided planner did, for CompileStats / benchmarks."""
+    """What the cost-guided planner did, for CompileStats."""
 
     mode: str = "greedy"
     plans_explored: int = 0        # candidate partitions scored (incl. greedy)

@@ -1,15 +1,10 @@
 """The unified analytic latency model — ONE place for device constants and
 roofline math.
 
-Before this module, cost knowledge was split three ways and drifted
-independently: ``core/perf_library.py`` carried a ``TpuSpec`` + per-op
-roofline miss handler, ``launch/roofline.py`` re-declared the same peak
-FLOPs / HBM / ICI numbers as module constants, and ``launch/costmodel.py``
-walked jaxprs with its own byte conventions.  ``DeviceSpec`` is now the
-single source of truth for hardware constants (both older sites re-export
-it) and ``LatencyModel`` is the one scoring object shared by the fusion
-planner, the schedule tuner (through ``PerfLibrary.model``), and the
-module-level roofline table.
+``DeviceSpec`` is the single source of truth for hardware constants
+(``core/perf_library.py`` re-exports it) and ``LatencyModel`` is the one
+scoring object shared by the fusion planner and the schedule tuner (through
+``PerfLibrary.model``).
 
 What the model charges (see README "LatencyModel conventions"):
   * one ``launch_overhead_s`` per kernel plus ``grid_step_overhead_s`` per
@@ -55,9 +50,8 @@ from .schedule import (
 class DeviceSpec:
     """TPU v5e per-chip numbers — the single source of hardware truth.
 
-    ``core/perf_library.py`` re-exports this as ``TpuSpec`` and
-    ``launch/roofline.py`` derives its module constants from ``TPU_V5E``;
-    neither keeps its own copy anymore.
+    ``core/perf_library.py`` re-exports this as ``TpuSpec`` and keeps no
+    copy of its own.
     """
 
     peak_flops_bf16: float = 197e12
@@ -173,9 +167,8 @@ class LatencyModel:
 
     One instance is shared across the whole compile: the fusion planner
     scores candidate partitions, ``PerfLibrary`` uses ``op_time`` as its
-    miss handler, ``tuning.score`` finishes with ``kernel_time``, and
-    ``launch/roofline.py`` builds its table from the ``*_time`` roofline
-    terms — all against the same ``DeviceSpec``.
+    miss handler, and ``tuning.score`` finishes with ``kernel_time`` — all
+    against the same ``DeviceSpec``.
     """
 
     def __init__(self, spec: Optional[DeviceSpec] = None):
@@ -336,16 +329,6 @@ class LatencyModel:
         # re-tiled read by the consumer phase, both through VMEM
         total += 2.0 * stitched.interface_bytes / spec.vmem_bw
         return total
-
-    # ---- module-level roofline terms (launch/roofline.py) ----------------
-    def compute_time(self, flops: float, chips: int = 1) -> float:
-        return flops / (chips * self.spec.peak_flops_bf16)
-
-    def memory_time(self, nbytes: float, chips: int = 1) -> float:
-        return nbytes / (chips * self.spec.hbm_bw)
-
-    def collective_time(self, nbytes: float, chips: int = 1) -> float:
-        return nbytes / (chips * self.spec.ici_bw)
 
     # ---- per-collective-op time (shard-aware plans) ----------------------
     def collective_op_time(self, instr: Instruction, group_size: int) -> float:

@@ -29,8 +29,7 @@ measured cost when a key hits, analytic as the cold-start prior) and
 ``core/pipeline.py`` (``AutotunePass`` measures each unique committed kernel
 once and remembers it).  ``CompileStats`` reports
 ``measured_hits/measured_misses/measurements_taken/model_error_pct`` so the
-analytic model's error is visible per compile — and per bench graph in
-``benchmarks/baseline.json``.
+analytic model's error is visible per compile.
 """
 from __future__ import annotations
 

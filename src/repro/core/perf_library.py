@@ -13,9 +13,9 @@ the schedule into a Pallas micro-kernel and time it; the interface is
 identical.
 
 The hardware constants and roofline math used to live here; they moved to
-``core/latency.py`` so the fusion planner, the tuner, and the launch-time
-roofline table score against ONE device spec.  ``TpuSpec`` and ``CostModel``
-remain as aliases for existing callers.
+``core/latency.py`` so the fusion planner and the tuner score against ONE
+device spec.  ``TpuSpec`` and ``CostModel`` remain as aliases for existing
+callers.
 """
 from __future__ import annotations
 

@@ -4,7 +4,6 @@ from .elastic import choose_mesh_shape, make_elastic_mesh, reshard_state
 from .sharding import (
     batch_shardings,
     batch_spec,
-    cache_shardings,
     cache_spec,
     opt_state_shardings,
     param_layout,

@@ -177,20 +177,6 @@ def cache_spec(path: str, shape: Tuple[int, ...], mesh: Mesh,
     return P(*dims)
 
 
-def cache_shardings(cache_tree, mesh: Mesh, global_batch: int):
-    def walk(path, node):
-        if isinstance(node, dict):
-            return {k: walk(f"{path}/{k}", v) for k, v in node.items()}
-        if isinstance(node, (tuple, list)):
-            vals = [walk(f"{path}/{i}", v) for i, v in enumerate(node)]
-            return type(node)(vals)
-        return NamedSharding(
-            mesh, cache_spec(path, tuple(node.shape), mesh, global_batch)
-        )
-
-    return walk("", cache_tree)
-
-
 def current_mesh() -> Optional[Mesh]:
     """The mesh installed by a ``with mesh:`` context, if any."""
     try:

@@ -449,7 +449,7 @@ class StitchedFunction:
         if self._fallback_jit is None:
             if self.mesh is not None:
                 # The sharded oracle: the same shard_map placement, compiled
-                # whole by XLA — also the bit-parity reference in benchmarks.
+                # whole by XLA — also the bit-parity reference in the tests.
                 self._fallback_jit = jax.jit(
                     wrap_shard_map(
                         self._fn, self.mesh, self.in_specs, self.out_specs

@@ -4,8 +4,8 @@
         --steps 100 --batch 8 --seq 64 [--reduced] [--ckpt-dir /path]
 
 On a real TPU fleet this binary runs once per host (jax.distributed
-initializes from the TPU environment); the mesh comes from
-``make_production_mesh`` and every step is pjit-sharded by
+initializes from the TPU environment); ``--mesh data,model`` builds the
+mesh with ``jax.make_mesh`` and every step is pjit-sharded by
 ``repro.distributed.sharding``.  The config trains at its published width
 unless ``--reduced`` is given, which trains a small same-family config
 end-to-end with the identical code path minus the mesh.

@@ -17,7 +17,7 @@ Arbitration between the two is the TTFT-vs-latency tradeoff:
     (measured wait + EMA-estimated remaining chunk time) would overrun
     ``safety * ttft_slo_s``, prefill wins;
   * neither at risk (or both SLOs None, the default): strict alternation,
-    which is deterministic in ticks — what the traffic benchmark gates on.
+    which is deterministic in ticks — what the traffic tests rely on.
 """
 from __future__ import annotations
 

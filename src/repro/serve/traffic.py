@@ -10,7 +10,7 @@ only through the latency stamps (TTFT / latency percentiles).
 
 ``run_trace`` drives any engine exposing ``admit / tick / busy /
 inflight`` (both the slot-ring and the paged engine do), which is how the
-benchmark compares the two under identical offered load.
+tests compare the two under identical offered load.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-from .optimizer import AdamWConfig, AdamWState, adamw_init, adamw_init_specs, adamw_update, lr_at
+from .optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update, lr_at
 from .trainer import (
     FailureInjector,
     StragglerWatchdog,

@@ -78,18 +78,6 @@ def adamw_init(params) -> AdamWState:
     )
 
 
-def adamw_init_specs(param_specs) -> AdamWState:
-    """ShapeDtypeStruct mirror for dry runs."""
-    def sds(p):
-        return jax.ShapeDtypeStruct(p.shape, jnp.float32)
-
-    return AdamWState(
-        step=jax.ShapeDtypeStruct((), jnp.int32),
-        m=jax.tree.map(sds, param_specs),
-        v=jax.tree.map(sds, param_specs),
-    )
-
-
 def adamw_update(
     cfg: AdamWConfig, params, grads, state: AdamWState
 ) -> Tuple[Any, AdamWState, Dict[str, jax.Array]]:

@@ -1,9 +1,7 @@
 import os
-import sys
 
 # Sharded-compile tests need a real multi-device mesh; jax locks the device
-# count on first init, so the flag must be set before `import jax` (the same
-# idiom as launch/dryrun.py, which sets its own 512-way count per-process).
+# count on first init, so the flag must be set before `import jax`.
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
 ).strip()
@@ -36,7 +34,6 @@ def compile_and_compare(module, feeds, rtol=2e-5, atol=2e-5, **opt_kwargs):
     return compiled
 
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
 from graphs import random_feeds as make_feeds  # noqa: E402,F401  (canonical copy)
 
 

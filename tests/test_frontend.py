@@ -5,8 +5,6 @@ The parity contract: for each ported benchmark family, ``stitch(fn)`` must
 produce outputs allclose to ``jax.jit(fn)`` AND commit the same kernel
 counts as compiling the hand-built StitchIR module of the same computation.
 """
-import os
-import sys
 from dataclasses import replace
 
 import numpy as np
@@ -25,8 +23,7 @@ from repro import (
 from repro.core import GraphBuilder, Module, trace
 from repro.frontend import SUPPORTED_PRIMITIVES, lower_jaxpr
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from graphs import JNP_FAMILIES  # noqa: E402
+from graphs import JNP_FAMILIES
 
 OPTS = StitchOptions(max_blocks=32)
 

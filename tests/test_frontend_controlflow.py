@@ -110,6 +110,7 @@ def test_traced_replay_reduces_dispatches():
     st(h, w)
     s = st.stats
     assert s.replay_mode == "jit"
+    assert s.traced_dispatches_per_call == 1   # the whole loop, one dispatch
     assert s.traced_dispatches_per_call < s.eager_dispatches_per_call
 
 
@@ -325,6 +326,17 @@ def test_stitched_train_step_matches_jit_trajectory():
     assert st.num_fallbacks == 0
     assert st.num_compiles == 1  # the whole train step is ONE plan
     assert st.stats.stitched_kernels >= 1
+
+
+def test_stitched_train_step_launches_fewer_kernels_than_unfused():
+    """Forward, backward and AdamW compile as one plan that launches fewer
+    kernels than the unfused XLA-style baseline of the same step."""
+    st = make_stitched_train_step(mlp_loss_batch, AdamWConfig(), options=OPTS)
+    params, x, y = _mlp_data(seed=7)
+    st(params, adamw_init(params), (x, y))
+    s = st.stats
+    assert st.num_fallbacks == 0 and st.num_compiles == 1
+    assert s.stitched_kernels + s.standalone_kernels == 11 < s.xla_baseline_kernels
 
 
 def mlp_loss_batch(params, batch):

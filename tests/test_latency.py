@@ -25,18 +25,6 @@ def test_perf_library_spec_is_the_latency_spec():
     assert lib.model.spec is TPU_V5E
 
 
-def test_roofline_constants_derive_from_device_spec():
-    from repro.launch import roofline
-
-    assert roofline.PEAK_FLOPS == TPU_V5E.peak_flops_bf16
-    assert roofline.HBM_BW == TPU_V5E.hbm_bw
-    assert roofline.ICI_BW == TPU_V5E.ici_bw
-    m = LatencyModel()
-    assert m.compute_time(TPU_V5E.peak_flops_bf16) == pytest.approx(1.0)
-    assert m.memory_time(TPU_V5E.hbm_bw, chips=2) == pytest.approx(0.5)
-    assert m.collective_time(TPU_V5E.ici_bw) == pytest.approx(1.0)
-
-
 def test_tuning_uses_shared_trivial_convention():
     from repro.core import latency, tuning
 

@@ -22,10 +22,7 @@ from repro.core import (
 )
 from repro.core.measure import MEASURE_SCHEMA_VERSION
 from repro.core.perf_library import JsonStore
-
-import sys
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from graphs import (  # noqa: E402
+from graphs import (
     ALL_GRAPHS,
     reduce_towers_graph,
     stitch_pipeline_graph,

@@ -17,10 +17,7 @@ from repro.core import (
     reference_execute,
     trace,
 )
-
-import sys, os
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from graphs import (  # noqa: E402
+from graphs import (
     broadcast_towers_graph,
     reduce_towers_graph,
     stacked_transformer_graph,

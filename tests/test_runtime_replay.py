@@ -1,16 +1,11 @@
 """Traced ExecutionPlan replay: jit/eager oracle parity, dispatch
 accounting, buffer-release correctness, and feed validation."""
-import os
-import sys
-
 import numpy as np
 import pytest
 
 from conftest import make_feeds as _feeds
+from graphs import ALL_GRAPHS
 from repro.core import GraphBuilder, StitchOptions, compile_module, trace
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-from graphs import ALL_GRAPHS  # noqa: E402
 
 OPTS = StitchOptions(max_blocks=64)
 

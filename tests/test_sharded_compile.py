@@ -6,12 +6,15 @@ Runs on the 8 host-platform CPU devices conftest.py forces (the
 every parity check compares the stitched plan bit-for-bit against the
 ``jax.jit(shard_map(fn))`` oracle.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from graphs import TP_FAMILIES
 from repro.core.compiler import StitchOptions, compile_module
 from repro.core.ir import GraphBuilder, infer_shape
 from repro.core.shard import (
@@ -234,6 +237,40 @@ def test_stitch_sharded_bitwise_parity(rng):
     assert s.collective_breaks_spanned >= 1
     # plan cache: second call recompiles nothing and stays bit-identical
     assert jnp.all(sharded(*args) == oracle) and sharded.num_compiles == 1
+
+
+#: per-device kernels of each tensor-parallel family's sharded plan
+TP_KERNELS = {"NMT_TP": 4, "Stacked_TP": 17}
+
+
+@pytest.mark.parametrize("family", sorted(TP_FAMILIES))
+def test_sharded_plan_launches_no_more_than_single_device(rng, family):
+    """Sharding never costs launches: the per-device plan commits no more
+    kernels than the single-device plan of the same computation, and at
+    least one all-reduce keeps stitched kernels on both of its sides."""
+    from repro import stitch
+
+    fam = TP_FAMILIES[family]
+    opts = StitchOptions(max_blocks=64, **fam["options"])
+    specs = fam["specs"]()
+    tp_fn = functools.partial(fam["fn"], axis="model")
+    args = fam["args"](rng)
+    mesh = _mesh()
+    single = stitch(fam["fn"], options=opts)
+    single(*args)
+    sharded = stitch(tp_fn, options=opts, mesh=mesh, **specs)
+    out = sharded(*args)
+    oracle = jax.jit(
+        wrap_shard_map(tp_fn, mesh, specs["in_specs"], specs["out_specs"])
+    )(*args)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(oracle), rtol=2e-5, atol=2e-5
+    )
+    ss, st = single.stats, sharded.stats
+    perdev = st.stitched_kernels + st.standalone_kernels
+    assert perdev == TP_KERNELS[family]
+    assert perdev <= ss.stitched_kernels + ss.standalone_kernels
+    assert st.collective_breaks_spanned >= 1
 
 
 def test_stitch_sharded_all_gather_reduce_scatter(rng):

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from graphs import ALL_GRAPHS
 from repro.core import (
     CompilationState,
     FusedComputation,
@@ -498,6 +499,33 @@ def test_random_graphs_compile_clean_under_strict(planner):
         )
         assert comp.stats.verify_boundaries == 8
         assert comp.stats.verify_warnings == 0
+
+
+#: kernels (stitched + standalone) each benchmark graph compiles to at
+#: max_blocks=64 under each planner: a planner or pass change that alters
+#: a plan's launches fails here by graph name (update the pin if intended)
+GRAPH_KERNELS = {
+    "cost": {
+        "LR": 6, "W2V": 1, "RNN": 7, "BiRNN": 7, "Speech": 3, "NMT": 1,
+        "Stacked": 9, "ReduceTowers": 1, "BcastHeavy": 1, "StitchPipe": 1,
+    },
+    "greedy": {
+        "LR": 6, "W2V": 1, "RNN": 7, "BiRNN": 7, "Speech": 3, "NMT": 1,
+        "Stacked": 9, "ReduceTowers": 6, "BcastHeavy": 5, "StitchPipe": 9,
+    },
+}
+
+
+@pytest.mark.parametrize("planner", ["cost", "greedy"])
+@pytest.mark.parametrize("name", list(ALL_GRAPHS))
+def test_benchmark_graph_compiles_clean_under_strict(name, planner):
+    comp = compile_module(
+        ALL_GRAPHS[name](),
+        StitchOptions(max_blocks=64, planner=planner, verify="strict"),
+    )
+    s = comp.stats
+    assert s.verify_boundaries == 8
+    assert s.stitched_kernels + s.standalone_kernels == GRAPH_KERNELS[planner][name]
 
 
 try:  # the hypothesis variant explores the same space adversarially
