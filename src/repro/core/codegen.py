@@ -55,8 +55,9 @@ from .schedule import (
     StitchedSolution,
     block_index,
     chunk_shape,
+    native_block,
     propagate,
-    tile_legal,
+    swap_minor,
 )
 from .signature import fusion_signature
 
@@ -105,25 +106,6 @@ def stamp_native_layouts(module, device) -> None:
             p.attrs["native_layout"] = True
         else:
             p.attrs.pop("native_layout", None)
-
-
-def _swap(t) -> tuple:
-    """``t`` with its last two entries exchanged."""
-    t = tuple(t)
-    return t[:-2] + (t[-1], t[-2])
-
-
-def _native(instr: Instruction, chunk, windowed: bool = False) -> bool:
-    """Whether the kernel reads input ``instr`` in its native layout: a
-    stamped parameter whose swapped block Mosaic can tile.  Not when
-    ``windowed`` (held whole across a grid of blocks): a block's window of
-    it would then start at a traced offset on its lane dim, which Mosaic
-    takes only at multiples of 128."""
-    return (
-        bool(instr.attrs.get("native_layout"))
-        and not windowed
-        and tile_legal(_swap(instr.shape), _swap(chunk), instr.dtype)
-    )
 
 
 def _compiler_params(vmem_need: int):
@@ -192,7 +174,7 @@ class _MinorSwapped:
 
     @property
     def shape(self) -> tuple:
-        return _swap(self.raw.shape)
+        return swap_minor(self.raw.shape)
 
     @property
     def value(self):
@@ -321,7 +303,7 @@ def _emit_instr(instr: Instruction, sched: Sched, ovals: List, b):
     # a swapped input taken as it is: the transpose that swaps it back is
     # the block the ref holds, and a dot contracts on its last dim; any
     # other swap of the minor dims is deferred to its consumers
-    if op == "transpose" and tuple(a["perm"]) == _swap(range(len(instr.shape))):
+    if op == "transpose" and tuple(a["perm"]) == swap_minor(range(len(instr.shape))):
         if _swapped_input(ovals[0]):
             return ovals[0].raw
         return _MinorSwapped(_value(ovals[0]))
@@ -430,7 +412,7 @@ def emit_fusion(
             return _full_spec(shape)
         if native:
             return pl.BlockSpec(
-                _swap(chunk_shape(shape, sched)),
+                swap_minor(chunk_shape(shape, sched)),
                 functools.partial(_swapped_block_index, shape, sched),
             )
         return pl.BlockSpec(
@@ -439,7 +421,7 @@ def emit_fusion(
 
     in_scheds = [assign.get(i.id, REPLICATED) for i in inputs]
     native = tuple(
-        _native(i, chunk_shape(i.shape, s), blocks > 1 and s.kind == "replicated")
+        native_block(i, chunk_shape(i.shape, s), blocks > 1 and s.kind == "replicated")
         for i, s in zip(inputs, in_scheds, strict=True)
     )
     in_specs = [
@@ -528,7 +510,7 @@ def _full_spec(shape) -> pl.BlockSpec:
 
 
 def _swapped_block_index(shape, sched: Sched, b) -> tuple:
-    return _swap(block_index(shape, sched, b))
+    return swap_minor(block_index(shape, sched, b))
 
 
 def _crossing(a, instr: Instruction, native: bool):
@@ -595,9 +577,9 @@ def emit_stitched_fusion(
     inputs = fusion.inputs
     roots = fusion.roots
 
-    native = tuple(_native(i, i.shape) for i in inputs)
+    native = tuple(native_block(i, i.shape) for i in inputs)
     in_specs = [
-        _full_spec(_swap(i.shape) if n else i.shape)
+        _full_spec(swap_minor(i.shape) if n else i.shape)
         for i, n in zip(inputs, native, strict=True)
     ]
     out_specs = [_full_spec(r.shape) for r in roots]

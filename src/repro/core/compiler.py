@@ -243,6 +243,9 @@ class CompileStats:
     # plan's device lays out with their minor dims swapped, read so by the
     # kernel instead of after an XLA relayout copy (0 on a CPU)
     native_layout_operands: int = 0
+    # kernel instances whose block of a batched-dot operand holds whole
+    # matrices of more than one batch element (``LaunchStats``)
+    dot_batch_blocks: int = 0
     # the executable's live LaunchStats, which the per-call counters read
     runtime: Optional[LaunchStats] = field(default=None, repr=False, compare=False)
 
@@ -519,6 +522,7 @@ def build_outputs(state: CompilationState) -> None:
         sharded_instrs=state.shard_stats.get("sharded_instrs", 0),
         interpret=state.options.interpret,
         native_layout_operands=sum(sum(p.kernel.native) for p in state.planned),
+        dot_batch_blocks=st.dot_batch_blocks,
         runtime=executable.execution_plan.stats,
     )
 

@@ -82,6 +82,9 @@ class LaunchStats:
     # or converted with ``jnp.asarray`` and their shape checked
     feeds_passed: int = 0
     feeds_converted: int = 0
+    # kernel instances whose block of a batched-dot operand holds whole
+    # matrices of more than one batch element (``schedule.DOT_BLOCK_LIMIT``)
+    dot_batch_blocks: int = 0
 
     @property
     def total_non_library(self) -> int:
@@ -854,6 +857,10 @@ class StitchedExecutable:
         st.donated_buffers = rt.donated_buffers
         st.feeds_passed = rt.feeds_passed
         st.feeds_converted = rt.feeds_converted
+        st.dot_batch_blocks = sum(
+            1 for k in self.kernels.values()
+            if k.solution is not None and k.solution.dot_batch_block
+        )
         return st
 
     def execute_eager(self, feeds: Dict[str, object]) -> Dict[str, object]:

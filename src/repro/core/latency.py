@@ -8,7 +8,8 @@ scoring object shared by the fusion planner and the schedule tuner (through
 
 What the model charges (see README "LatencyModel conventions"):
   * one ``launch_overhead_s`` per kernel plus ``grid_step_overhead_s`` per
-    grid program;
+    grid program, and one block's share of the kernel's HBM copies, which
+    the grid's pipelining does not hide (``kernel_time``);
   * compute at roofline peak — MXU peak for dots (bf16 vs f32 by dtype),
     VPU-weighted flops for elementwise (``_EW_WEIGHT``) — derated by a
     lane-efficiency penalty when the chunk underfills the (8,128) tile;
@@ -41,7 +42,6 @@ from .schedule import (
     Sched,
     ScheduleSolution,
     StitchedSolution,
-    blocks_of,
     chunk_shape,
 )
 
@@ -194,23 +194,30 @@ class LatencyModel:
         elems = int(np.prod(chunk, dtype=np.int64)) if chunk else 1
         itemsize = np.dtype(instr.dtype).itemsize
         total_elems = elems * (launch_blocks if not replicated else copies)
-        # bytes: write output once per copy + read operands
+        # bytes: write output once per copy + read operands: the grid's
+        # blocks read a chunked operand once between them, a replicated
+        # one once each
         bytes_moved = total_elems * itemsize
         for o in instr.operands:
-            o_elems = o.num_elements if replicated else o.num_elements / max(
-                1, blocks_of(o.shape, sched) if sched.kind == "chunked" else 1
-            )
-            bytes_moved += o_elems * np.dtype(o.dtype).itemsize * copies
+            bytes_moved += o.num_elements * np.dtype(o.dtype).itemsize * copies
         flops = instr_flops(instr) * (copies if replicated else 1)
         eff = _lane_efficiency(chunk, spec)
         t_compute = flops / (self.peak_for(instr) * eff)
         t_memory = bytes_moved / (spec.hbm_bw * eff)
         return max(t_compute, t_memory)
 
-    def kernel_time(self, num_blocks: int, op_times_sum: float) -> float:
+    def kernel_time(
+        self, num_blocks: int, op_times_sum: float, io_bytes: float = 0.0
+    ) -> float:
+        """A kernel of ``num_blocks`` grid steps whose ops take
+        ``op_times_sum`` and which copies ``io_bytes`` to and from HBM.  The
+        grid hides each block's copies under another block's work, except
+        the first block's reads and the last one's writes: one block's
+        share of the copies is exposed."""
         return (
             self.spec.launch_overhead_s
             + num_blocks * self.spec.grid_step_overhead_s
+            + io_bytes / max(1, num_blocks) / self.spec.hbm_bw
             + op_times_sum
         )
 

@@ -19,7 +19,8 @@ Three phases, faithfully ported from GPU shared memory to TPU VMEM scratch:
 
   Kernel inputs and outputs are VMEM blocks too, and Pallas double-buffers
   each one to overlap the HBM copies with compute: the budget covers those
-  blocks twice plus the scratch, each at its tiled (padded) size.
+  blocks twice plus the scratch, each at its tiled (padded) size, a block
+  of a parameter read in its native layout at its stored (swapped) shape.
 
   3. **Space sharing** (§5.1.3): build a dominance tree from the root
      (Cooper-Harvey-Kennedy on the reverse dataflow graph) and let an op
@@ -36,7 +37,13 @@ from typing import Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from .ir import Instruction
-from .schedule import LANES, ScheduleSolution, StitchedSolution, chunk_shape, sublanes
+from .schedule import (
+    ScheduleSolution,
+    StitchedSolution,
+    block_vmem_bytes,
+    chunk_shape,
+    vmem_bytes,
+)
 
 #: Scoped VMEM a v5e kernel gets unless it asks for more — the default
 #: per-kernel budget (inputs/outputs double-buffered + scratch).
@@ -49,21 +56,6 @@ INLINE = "INLINE"
 
 class MemoryInfeasible(Exception):
     """Required buffers exceed the VMEM budget — feedback to fusion."""
-
-
-def _round_up(n: int, m: int) -> int:
-    return -(-int(n) // m) * m
-
-
-def vmem_bytes(shape: Tuple[int, ...], dtype) -> int:
-    """Bytes a VMEM buffer of ``shape`` takes once padded to the tiling
-    (rank < 2 lays out as one row, rank 0 as a (1, 1) block)."""
-    shape = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
-    lead = int(np.prod(shape[:-2], dtype=np.int64))
-    return (
-        lead * _round_up(shape[-2], sublanes(dtype)) * _round_up(shape[-1], LANES)
-        * np.dtype(dtype).itemsize
-    )
 
 
 def io_blocks(members: List[Instruction], roots: List[Instruction]) -> List[Instruction]:
@@ -203,6 +195,14 @@ def _feeds_dot_through_shape_ops(instr: Instruction, member_ids: Set[int]) -> bo
     return False
 
 
+def _io_block_bytes(instr: Instruction, solution: ScheduleSolution) -> int:
+    """VMEM of ``instr``'s kernel block as ``codegen.emit_fusion`` lays it
+    out: a parameter read in its native layout at its stored shape."""
+    shape, sched = solution.block(instr)
+    windowed = solution.blocks > 1 and sched.kind == "replicated"
+    return block_vmem_bytes(instr, chunk_shape(shape, sched), windowed)
+
+
 def plan_memory(
     members: List[Instruction],
     roots: List[Instruction],
@@ -217,8 +217,7 @@ def plan_memory(
     io_bytes = 0
     if count_io:
         io_bytes = 2 * sum(
-            vmem_bytes(chunk_shape(*solution.block(i)), i.dtype)
-            for i in io_blocks(members, roots)
+            _io_block_bytes(i, solution) for i in io_blocks(members, roots)
         )
 
     # ---- phase 1: size requirements (candidates) -------------------------
@@ -436,7 +435,7 @@ def plan_stitched_memory(
         if not m.users or any(u.id not in group_ids for u in m.users)
     ]
     io_bytes = 2 * sum(
-        vmem_bytes(i.shape, i.dtype) for i in io_blocks(members, group_roots)
+        block_vmem_bytes(i, i.shape) for i in io_blocks(members, group_roots)
     )
 
     iface_bytes = sum(b.nbytes for b in interfaces.values())

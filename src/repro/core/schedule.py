@@ -143,6 +143,50 @@ def tile_legal(shape: Tuple[int, ...], chunk: Tuple[int, ...], dtype=np.float32)
     return lane_ok and sub_ok
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-int(n) // m) * m
+
+
+def swap_minor(t) -> tuple:
+    """``t`` with its last two entries exchanged."""
+    t = tuple(t)
+    return t[:-2] + (t[-1], t[-2])
+
+
+def native_block(instr: Instruction, chunk, windowed: bool = False) -> bool:
+    """Whether a kernel reads block ``chunk`` of input ``instr`` in its
+    native layout: ``instr`` is a parameter stamped ``native_layout`` (its
+    device lays it out with the two minor dims swapped) and the swapped
+    block tiles.  Not when ``windowed`` (held whole across a grid of
+    blocks): a block's window of it would then start at a traced offset on
+    its lane dim, which Mosaic takes only at multiples of 128.  The planner
+    sizes a block and codegen reads it by this one predicate."""
+    return (
+        bool(instr.attrs.get("native_layout"))
+        and not windowed
+        and tile_legal(swap_minor(instr.shape), swap_minor(chunk), instr.dtype)
+    )
+
+
+def vmem_bytes(shape: Tuple[int, ...], dtype) -> int:
+    """Bytes a VMEM buffer of ``shape`` takes once padded to the tiling
+    (rank < 2 lays out as one row, rank 0 as a (1, 1) block)."""
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
+    lead = int(np.prod(shape[:-2], dtype=np.int64))
+    return (
+        lead * _round_up(shape[-2], sublanes(dtype)) * _round_up(shape[-1], LANES)
+        * np.dtype(dtype).itemsize
+    )
+
+
+def block_vmem_bytes(instr: Instruction, chunk, windowed: bool = False) -> int:
+    """VMEM bytes of the kernel block ``chunk`` of ``instr``, padded to the
+    tiling in the layout the kernel reads it (``native_block``)."""
+    if native_block(instr, chunk, windowed):
+        chunk = swap_minor(chunk)
+    return vmem_bytes(chunk, instr.dtype)
+
+
 def reshape_legal(src: Tuple[int, ...], dst: Tuple[int, ...]) -> bool:
     """Whether Mosaic can reshape a ``src`` tile into ``dst`` in a kernel:
     the lane dim stays, and the sublane dim stays or both sides keep whole
@@ -324,6 +368,9 @@ class ScheduleSolution:
     root_scheds: Dict[int, Sched]
     # reshape roots applied outside the kernel (see ``resolve_schedules``)
     exits: frozenset = frozenset()
+    # some batched-dot operand's block holds whole matrices of more than
+    # one batch element (``DOT_BLOCK_LIMIT``)
+    dot_batch_block: bool = False
 
     def sched(self, instr: Instruction) -> Sched:
         return self.assignment[instr.id]
@@ -450,18 +497,25 @@ def resolve_schedules(
         if not tile_legal(shape, cs, instr.dtype):
             raise Unsatisfiable(f"{instr.name}: block {cs} breaks the tiling")
         limit = replicate_limit
-        if instr.id in matrices and all(c == 1 for c in cs[:-2]):
+        nbytes = int(np.prod(cs)) * np.dtype(instr.dtype).itemsize
+        if instr.id in matrices and cs[-2:] == tuple(shape[-2:]):
             limit = max(limit, DOT_BLOCK_LIMIT)
-        if int(np.prod(cs)) * np.dtype(instr.dtype).itemsize > limit:
+            nbytes = block_vmem_bytes(instr, cs)
+            sol.dot_batch_block |= _prod(cs[:-2]) > 1
+        if nbytes > limit:
             raise Unsatisfiable(f"{instr.name}: block {cs} > limit")
     return sol
 
 
-#: Cap on a block that holds one batch element's whole matrix of a batched
-#: dot operand: a quarter of the chip's default scoped VMEM, so the block
-#: fits double-buffered beside the kernel's other blocks (the memory plan
-#: checks the total).  Such a block is the finest the dot's schedule allows,
-#: so below this cap it is taken whatever ``replicate_limit`` says.
+#: Cap on a block of a batched dot operand that holds whole matrices, of one
+#: batch element or of several, counted as VMEM holds it (padded to the
+#: tiling, in the layout the kernel reads it): a quarter of the chip's
+#: default scoped VMEM, so the block fits double-buffered beside the
+#: kernel's other blocks (the memory plan checks the total).  One matrix is
+#: the finest block the dot's schedule allows, and a block of several reads
+#: the same bytes in fewer grid steps, so below this cap such a block is
+#: taken whatever ``replicate_limit`` says.  Blocks that cut a matrix keep
+#: ``replicate_limit``.
 DOT_BLOCK_LIMIT = 4 * 1024 * 1024
 
 
@@ -474,13 +528,9 @@ def _dot_matrix_inputs(members: List[Instruction]) -> set:
             continue
         for o in m.operands:
             out.add(o.id)
-            if o.opcode == "transpose" and tuple(o.attrs["perm"]) == _minor_swap(o.ndim):
+            if o.opcode == "transpose" and tuple(o.attrs["perm"]) == swap_minor(range(o.ndim)):
                 out.add(o.operands[0].id)
     return out
-
-
-def _minor_swap(n: int) -> Tuple[int, ...]:
-    return tuple(range(n - 2)) + (n - 1, n - 2)
 
 
 def any_satisfiable(

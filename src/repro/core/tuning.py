@@ -54,7 +54,12 @@ def score(
         total += lib.lookup(m, solution.sched(m), solution.blocks)
         if total >= best_so_far:
             return float("inf")
-    return lib.model.kernel_time(solution.blocks, total)
+    member_ids = {m.id for m in members}
+    inputs = {o.id: o for m in members for o in m.operands if o.id not in member_ids}
+    io_bytes = sum(o.bytesize for o in inputs.values()) + sum(
+        m.bytesize for m in members if m.id in solution.root_scheds
+    )
+    return lib.model.kernel_time(solution.blocks, total, io_bytes)
 
 
 def tune(

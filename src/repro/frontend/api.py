@@ -580,6 +580,8 @@ class StitchedFunction:
             f"(fusion ratio {s.fusion_ratio:.3f})",
             f"  plan cache       : {len(self._plans)} signature(s), "
             f"{self.num_compiles} compile(s), {self.num_fallbacks} fallback(s)",
+            f"  dot batch blocks : {s.dot_batch_blocks} kernel(s) read several "
+            "whole matrices a block",
         ]
         if s.loop_calls:
             lines.insert(

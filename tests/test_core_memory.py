@@ -5,7 +5,7 @@ import pytest
 
 from repro.core import GraphBuilder, MemoryInfeasible, Sched, plan_memory, resolve_schedules
 from repro.core.memory import ALLOC, INLINE, SHARE, dominance_tree, dominates, vmem_bytes
-from repro.core.schedule import ROW
+from repro.core.schedule import ROW, block_vmem_bytes
 
 
 def _resolve(b, root, split=0, sword=1):
@@ -156,3 +156,32 @@ def test_io_blocks_are_double_buffered_in_the_plan():
     assert plan.vmem_need >= plan.io_bytes
     with pytest.raises(MemoryInfeasible):
         plan_memory(members, roots, sol, vmem_limit=plan.io_bytes - 1)
+
+
+@pytest.mark.parametrize("native,nbytes", [
+    (True, 16 * 64 * 1024 * 4),      # stored (1, 16, 64, 1024): no padding
+    (False, 16 * 1024 * 128 * 4),    # row-major: 64 lanes padded to 128
+])
+def test_native_layout_block_is_counted_at_its_stored_shape(native, nbytes):
+    """qwen's K block (1, 16, 1024, 64): 4 MiB read in a v5e's layout,
+    8 MiB padded row-major.  The plan counts I/O blocks twice."""
+    b = GraphBuilder()
+    q = b.parameter("q", (32, 16, 1, 64), jnp.float32)
+    k = b.parameter("k", (32, 16, 1024, 64), jnp.float32)
+    if native:
+        k.instr.attrs["native_layout"] = True
+    s = b.dot(q, b.transpose(k, (0, 1, 3, 2)), fusable=True)
+    assert block_vmem_bytes(k.instr, (1, 16, 1024, 64)) == nbytes
+    if not native:
+        return            # over DOT_BLOCK_LIMIT: no schedule takes it
+    members, roots, sol = _resolve(b, s, sword=32)
+    plan = plan_memory(members, roots, sol)
+    q_block, s_block = 16 * 8 * 128 * 4, 16 * 8 * 1024 * 4
+    assert plan.io_bytes == 2 * (nbytes + q_block + s_block)
+
+
+def test_planner_and_codegen_share_the_native_layout_predicate():
+    from repro.core import codegen, memory, schedule
+
+    assert codegen.native_block is schedule.native_block
+    assert memory.block_vmem_bytes is schedule.block_vmem_bytes

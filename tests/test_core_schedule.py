@@ -17,7 +17,9 @@ from repro.core import (
     propagate,
     resolve_schedules,
 )
+from repro.core.perf_library import PerfLibrary
 from repro.core.schedule import ROW, COLUMN, block_index, reshape_legal, tile_legal
+from repro.core.tuning import tune
 
 
 # ---------------------------------------------------------------- blocks math
@@ -202,6 +204,66 @@ def test_dot_matrix_block_may_pass_replicate_limit():
     y = b.exp(x)
     with pytest.raises(Unsatisfiable, match="limit"):
         resolve_schedules([y.instr], [y.instr], {y.instr.id: Sched("chunked", 0, 64, ROW)})
+
+
+def _scores(q_shape, k_shape, native: bool):
+    """Masked attention scores and their row max, ``max(q @ k.T + mask)``,
+    with K stamped ``native_layout`` (a v5e's layout) when ``native``."""
+    b = GraphBuilder()
+    q = b.parameter("q", q_shape, jnp.float32)
+    k = b.parameter("k", k_shape, jnp.float32)
+    if native:
+        k.instr.attrs["native_layout"] = True
+    n = len(k_shape)
+    s = b.dot(q, b.transpose(k, tuple(range(n - 2)) + (n - 1, n - 2)), fusable=True)
+    mask = b.parameter("mask", s.shape, jnp.float32)
+    top = b.reduce(s + mask, (n - 1,), "max", keepdims=True)
+    members = [i for i in b.module.instructions if i.opcode != "parameter"]
+    return members, top.instr, k.instr
+
+
+QWEN = ((32, 16, 1, 64), (32, 16, 1024, 64))
+GRANITE = ((64, 12, 128), (64, 8192, 128))
+
+
+@pytest.mark.parametrize("shapes,native,blocks,k_block", [
+    # qwen's K in a v5e's layout: one row's 16 heads, 4 MiB as (16, 64, 1024)
+    (QWEN, True, 32, (1, 16, 1024, 64)),
+    # row-major, the same block pads 64 lanes to 128: 8 MiB, over the cap
+    (QWEN, False, 32, None),
+    # two rows: 8 MiB whatever the layout
+    (QWEN, True, 16, None),
+    # granite's MQA cache: one row's matrix is 4 MiB, two rows 8 MiB
+    (GRANITE, False, 64, (1, 8192, 128)),
+    (GRANITE, False, 32, None),
+])
+def test_dot_block_of_whole_matrices_up_to_the_cap(shapes, native, blocks, k_block):
+    """A batched dot's operand may take whole matrices of one or more batch
+    elements a block, up to ``DOT_BLOCK_LIMIT`` as VMEM holds the block."""
+    members, root, k = _scores(*shapes, native)
+    sched = Sched("chunked", 0, blocks, ROW)
+    if k_block is None:
+        with pytest.raises(Unsatisfiable, match="limit"):
+            resolve_schedules(members, [root], {root.id: sched})
+        return
+    sol = resolve_schedules(members, [root], {root.id: sched})
+    assert sol.blocks == blocks
+    assert chunk_shape(k.shape, sol.sched(k)) == k_block
+    assert sol.dot_batch_block == (np.prod(k_block[:-2]) > 1)
+
+
+@pytest.mark.parametrize("shapes,native,blocks", [
+    (QWEN, True, 32),       # the fewest grid steps that fit the cap
+    (QWEN, False, 64),      # row-major: 8 heads a block, 4 MiB padded
+    (GRANITE, False, 64),   # one row a block, as before
+])
+def test_tuner_takes_the_fewest_blocks_of_whole_matrices(shapes, native, blocks):
+    """The same bytes read in fewer, larger blocks: the latency model
+    charges the grid's steps and one block's exposed copies, so it picks
+    the coarsest schedule the cap allows."""
+    members, root, _ = _scores(*shapes, native)
+    tuned = tune(members, [root], PerfLibrary(), max_blocks=4096)
+    assert tuned.solution.blocks == blocks
 
 
 # ------------------------------------------------------------- TPU tiling

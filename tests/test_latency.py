@@ -10,7 +10,7 @@ from repro.core import (
     trace,
 )
 from repro.core.latency import TPU_V5E, instr_flops, instr_hbm_bytes
-from repro.core.schedule import REPLICATED, any_satisfiable
+from repro.core.schedule import REPLICATED, ROW, Sched, any_satisfiable
 
 
 # --------------------------------------------------- single source of truth
@@ -51,6 +51,27 @@ def test_kernel_time_charges_launch_and_grid_steps():
         TPU_V5E.launch_overhead_s + TPU_V5E.grid_step_overhead_s
     )
     assert model.kernel_time(64, 0.0) > model.kernel_time(1, 0.0)
+
+
+def test_kernel_time_exposes_one_blocks_share_of_the_copies():
+    model = LatencyModel()
+    io = 8 * 1024 * 1024
+    for blocks in (1, 4, 32):
+        assert model.kernel_time(blocks, 0.0, io) - model.kernel_time(blocks, 0.0) == (
+            pytest.approx(io / blocks / TPU_V5E.hbm_bw)
+        )
+
+
+def test_op_time_reads_a_chunked_operand_once_whatever_the_grid():
+    """The grid's blocks read a chunked operand once between them, so a
+    finer grid moves no fewer bytes."""
+    model = LatencyModel()
+    instr = _exp_module((512, 1024)).instructions[-1]
+    t1, t8, t64 = (
+        model.op_time(instr, Sched("chunked", 0, w, ROW), w) for w in (1, 8, 64)
+    )
+    assert t1 == pytest.approx(t8) == pytest.approx(t64)
+    assert model.op_time(instr, REPLICATED, 8) == pytest.approx(8 * t1)
 
 
 def test_standalone_time_includes_launch_overhead():

@@ -18,6 +18,7 @@ import pytest
 
 from repro import StitchOptions, stitch
 from repro.core import codegen, compile_module, reference_execute, trace
+from repro.core.schedule import chunk_shape, native_block
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -116,26 +117,27 @@ def test_swapped_block_off_the_tiling_stays_row_major():
     m = trace(lambda b, x: b.exp(x), ("x", (16, 256), jnp.float32))
     x = m.parameters[0]
     x.attrs["native_layout"] = True
-    assert codegen._native(x, (16, 256))
-    assert not codegen._native(x, (8, 256))
-    assert codegen._native(x, (16, 128))
-    assert not codegen._native(x, (16, 256), windowed=True)
+    assert native_block(x, (16, 256))
+    assert not native_block(x, (8, 256))
+    assert native_block(x, (16, 128))
+    assert not native_block(x, (16, 256), windowed=True)
     del x.attrs["native_layout"]
-    assert not codegen._native(x, (16, 256))
+    assert not native_block(x, (16, 256))
 
 
 @pytest.mark.parametrize("max_blocks", [2, 1])
 def test_parameter_held_whole_across_blocks_stays_row_major(monkeypatch, max_blocks):
-    """``w`` (1, 256) is held whole while ``x`` (64, 256) goes in row
+    """``w`` (1, 2048) is held whole while ``x`` (64, 2048) goes in row
     blocks: with a grid of blocks each block would window ``w`` at a
     traced offset, on its lane dim once swapped, so both stay row-major
-    (``x`` for its tiling); with one block both are read swapped."""
+    (``x`` for its tiling); with one block both are read swapped.  (At
+    512 KiB ``x`` is large enough that the tuner takes the grid it may.)"""
     monkeypatch.setattr(codegen, "default_minor_to_major", _swap_all)
 
     def f(b, x, w):
-        return b.exp(x) * b.broadcast(b.reshape(w, (256,)), (64, 256), (1,))
+        return b.exp(x) * b.broadcast(b.reshape(w, (2048,)), (64, 2048), (1,))
 
-    m = trace(f, ("x", (64, 256), jnp.float32), ("w", (1, 256), jnp.float32))
+    m = trace(f, ("x", (64, 2048), jnp.float32), ("w", (1, 2048), jnp.float32))
     rng = np.random.RandomState(0)
     feeds = {p.name: rng.uniform(-1, 1, p.shape).astype(np.float32) for p in m.parameters}
     compiled = compile_module(
@@ -148,12 +150,13 @@ def test_parameter_held_whole_across_blocks_stays_row_major(monkeypatch, max_blo
 
 
 def test_row_blocked_parameter_stays_row_major(monkeypatch):
-    """Softmax rows of a (64, 256) parameter in blocks of (8, 256): the
-    parameter is stamped, but its swapped block would break the tiling."""
+    """Softmax rows of a (384, 640) parameter in blocks of (192, 640):
+    the parameter is stamped, but its swapped block would break the
+    tiling (192 lanes of 384)."""
     monkeypatch.setattr(codegen, "default_minor_to_major", _swap_all)
-    m = trace(lambda b, x: b.softmax(x, dim=-1), ("x", (64, 256), jnp.float32))
-    feeds = {"x": np.random.RandomState(0).uniform(-1, 1, (64, 256)).astype(np.float32)}
-    compiled = compile_module(m, StitchOptions(max_blocks=8), device=jax.devices()[0])
+    m = trace(lambda b, x: b.softmax(x, dim=-1), ("x", (384, 640), jnp.float32))
+    feeds = {"x": np.random.RandomState(0).uniform(-1, 1, (384, 640)).astype(np.float32)}
+    compiled = compile_module(m, StitchOptions(max_blocks=2), device=jax.devices()[0])
     assert m.parameters[0].attrs.get("native_layout")
     (k,) = {id(k): k for k in compiled.executable.kernels.values()}.values()
     assert k.blocks > 1 and k.native == (False,)
@@ -175,8 +178,13 @@ def test_cpu_query_changes_nothing():
 
 
 def test_cpu_plan_of_a_full_width_layer_keeps_its_kernel_names():
-    """The row-major attention kernels of the decode benchmark keep the
-    names that the recorded chip slice in ``tests/bench/data`` carries."""
+    """The row-major attention kernels of the decode benchmark keep their
+    names on a CPU, where nothing is stamped: the weighted sum
+    ``stitch_62d7c765`` as the recorded chip slice in ``tests/bench/data``
+    carries it, and the scores ``stitch_e7f40eca``, which took in the
+    reshape of its result once a block of 8 heads' whole K matrices (4 MiB
+    padded) became legal; the slice carries it before that, as
+    ``stitch_aa85ce72``."""
     cfg = json.load(open(os.path.join(ROOT, "bench/configs/qwen1.5-0.5b-f32-5layers.json")))
     cfg["num_hidden_layers"] = 1
     traffic = json.load(open(os.path.join(ROOT, "bench/traffic/decode.json")))
@@ -185,5 +193,26 @@ def test_cpu_plan_of_a_full_width_layer_keeps_its_kernel_names():
     ).compile()
     assert compiled.stats.native_layout_operands == 0
     names = {k.name for k in compiled.executable.kernels.values()}
-    assert {"stitch_aa85ce72", "stitch_62d7c765"} <= names
+    assert {"stitch_e7f40eca", "stitch_62d7c765"} <= names
 
+
+
+def test_dot_batch_blocks_count_kernels_that_read_several_matrices_a_block():
+    """``CompileStats``, ``launch_stats()`` and ``report()`` count the
+    kernel instances whose block of a batched-dot operand holds whole
+    matrices of more than one batch element: here K's or V's two heads."""
+    fn, args = _decode(layers=2)
+    st = stitch(fn)
+    st(*args)
+    ex = st._last.compiled.executable
+    readers = [
+        k for k in ex.kernels.values()
+        if k.solution is not None and k.solution.dot_batch_block
+    ]
+    assert readers
+    for k in readers:
+        kv = [i for i in k.inputs if len(i.shape) == 4 and i.shape[2] == TRAFFIC["context"]]
+        if kv[0].id in k.solution.assignment:      # the signature's representative
+            assert chunk_shape(kv[0].shape, k.solution.sched(kv[0]))[1:] == kv[0].shape[1:]
+    assert st.stats.dot_batch_blocks == ex.launch_stats().dot_batch_blocks == len(readers)
+    assert f"dot batch blocks : {len(readers)} kernel(s)" in st.report()

@@ -25,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from graphs import ALL_GRAPHS, nmt_fn, softmax_transpose_fn, swiglu_fn
 from repro import StitchOptions, stitch
 from repro.core import compile_module
+from repro.core.codegen import VMEM_HEADROOM
+from repro.core.memory import SCOPED_VMEM_BYTES
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -114,7 +116,9 @@ def _param_copies(entry: str):
 def test_decode_layer_reads_kv_in_the_v5e_layout(one_chip):
     """One full-width layer of the decode program: K and V, f32[32, 16,
     1024, 64] laid out {2,3,1,0} on a v5e, reach their kernels through a
-    bitcast, with no relayout copy in the replay segment."""
+    bitcast, with no relayout copy in the replay segment.  Each of the two
+    kernels reads one row's 16 heads, 4 MiB, a block: 32 blocks, inside the
+    chip's default scoped VMEM."""
     cfg = json.load(open(os.path.join(ROOT, "bench/configs/qwen1.5-0.5b-f32-5layers.json")))
     cfg["num_hidden_layers"] = 1
     traffic = json.load(open(os.path.join(ROOT, "bench/traffic/decode.json")))
@@ -125,6 +129,12 @@ def test_decode_layer_reads_kv_in_the_v5e_layout(one_chip):
     fn = qwen_decode.program(cfg, traffic)
     compiled = stitch(fn, options=StitchOptions(interpret=False)).lower(*shapes).compile()
     assert compiled.stats.native_layout_operands == 2
+    assert compiled.stats.dot_batch_blocks == 2
+    kv = {k.name: k for k in compiled.executable.kernels.values() if any(k.native)}
+    assert len(kv) == 2
+    for k in kv.values():
+        assert k.blocks == 32
+        assert k.plan.vmem_need + VMEM_HEADROOM <= SCOPED_VMEM_BYTES
     ep = compiled.executable.execution_plan
     (seg,) = ep._segments
     seg.build(lambda: None)
